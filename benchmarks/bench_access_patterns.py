@@ -33,4 +33,6 @@ def run() -> list[str]:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     print("\n".join(run()))

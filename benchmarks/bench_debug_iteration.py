@@ -149,5 +149,7 @@ def run_sweep() -> list[str]:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     print("\n".join(run()))
     print("\n".join(run_sweep()))

@@ -149,4 +149,6 @@ def run_full() -> list[str]:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     print("\n".join(run(quick="--full" not in sys.argv[1:])))

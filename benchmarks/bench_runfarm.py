@@ -198,4 +198,6 @@ def main(argv: List[str]) -> int:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     sys.exit(main(sys.argv[1:]))

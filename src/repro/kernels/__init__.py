@@ -1,3 +1,13 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
+"""Pallas kernels: the "hardware" that FireBridge co-verifies."""
+import jax
+import jax.numpy as jnp
+
+
+def contract_precision(dtype):
+    """MXU precision for a dot over operands stored as ``dtype``.
+
+    f32 data gets a full f32 contraction: a TPU's default for an f32 dot
+    is one bf16 pass, which an f32 oracle sees as a divergence.  bf16 data
+    is exact in that one pass, so it keeps the default.
+    """
+    return jax.lax.Precision.HIGHEST if dtype == jnp.float32 else None

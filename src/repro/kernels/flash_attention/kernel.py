@@ -21,6 +21,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
+from repro.kernels import contract_precision
+
 NEG = -1.0e30
 
 
@@ -65,11 +67,13 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_s, l_s, acc_s, *,
 
     @pl.when(_tile_live(i, j, bq, bk, causal, window))
     def _compute():
+        prec = contract_precision(q_ref.dtype)
         q = q_ref[0, 0].astype(jnp.float32)              # (bq, D)
         k = k_ref[0, 0].astype(jnp.float32)              # (bk, D)
         v = v_ref[0, 0].astype(jnp.float32)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
+                                preferred_element_type=jnp.float32,
+                                precision=prec) * scale
         mask = _tile_mask(i, j, bq, bk, causal, window)
         s = jnp.where(mask, s, NEG)
         m_prev = m_s[...]                                # (bq, 1)
@@ -79,19 +83,25 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_s, l_s, acc_s, *,
         corr = jnp.exp(m_prev - m_new)                   # (bq, 1)
         l_s[...] = l_s[...] * corr + jnp.sum(p, axis=1, keepdims=True)
         acc_s[...] = acc_s[...] * corr + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32,
+            precision=prec)
         m_s[...] = m_new
 
     @pl.when(j == nk - 1)
     def _finalize():
         l = jnp.maximum(l_s[...], 1e-30)
         o_ref[0, 0] = (acc_s[...] / l).astype(o_ref.dtype)
-        lse_ref[0, 0] = (m_s[...] + jnp.log(l))[:, 0]
+        lse_ref[0, 0] = m_s[...] + jnp.log(l)
 
 
 def flash_fwd(q, k, v, *, causal: bool, window: int = 0, bq: int = 512,
               bk: int = 512, interpret: bool = True):
-    """q (B,H,Sq,D); k/v (B,KH,Skv,D) -> (out (B,H,Sq,D), lse (B,H,Sq))."""
+    """q (B,H,Sq,D); k/v (B,KH,Skv,D) -> (out (B,H,Sq,D), lse (B,H,Sq,1)).
+
+    ``lse`` keeps a trailing unit dim so its (1, 1, bq, 1) block meets the
+    TPU (8, 128) tiling rule (bq rows, a full minor dim); a (1, 1, bq)
+    block over (B, H, Sq) does not once H > 1.
+    """
     from jax.experimental.pallas import tpu as pltpu
 
     B, H, Sq, D = q.shape
@@ -116,11 +126,11 @@ def flash_fwd(q, k, v, *, causal: bool, window: int = 0, bq: int = 512,
         ],
         out_specs=[
             pl.BlockSpec((1, 1, bq, D), lambda b, h, i, j: (b, h, i, 0)),
-            pl.BlockSpec((1, 1, bq), lambda b, h, i, j: (b, h, i)),
+            pl.BlockSpec((1, 1, bq, 1), lambda b, h, i, j: (b, h, i, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B, H, Sq, D), q.dtype),
-            jax.ShapeDtypeStruct((B, H, Sq), jnp.float32),
+            jax.ShapeDtypeStruct((B, H, Sq, 1), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((bq, 1), jnp.float32),   # running max
@@ -151,24 +161,29 @@ def _dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
     @pl.when(_tile_live(i, j, bq, bk, causal, window))
     def _compute():
+        prec = contract_precision(q_ref.dtype)
         q = q_ref[0, 0].astype(jnp.float32)
         k = k_ref[0, 0].astype(jnp.float32)
         v = v_ref[0, 0].astype(jnp.float32)
         do = do_ref[0, 0].astype(jnp.float32)
-        lse = lse_ref[0, 0]                               # (bq,)
-        delta = delta_ref[0, 0]                           # (bq,)
+        lse = lse_ref[0, 0]                               # (bq, 1)
+        delta = delta_ref[0, 0]                           # (bq, 1)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
+                                preferred_element_type=jnp.float32,
+                                precision=prec) * scale
         mask = _tile_mask(i, j, bq, bk, causal, window)
-        p = jnp.exp(jnp.where(mask, s, NEG) - lse[:, None])
+        p = jnp.exp(jnp.where(mask, s, NEG) - lse)
         p = jnp.where(mask, p, 0.0)                       # (bq, bk)
         dv_ref[0, 0] += jax.lax.dot_general(
-            p, do, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+            p, do, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32,
+            precision=prec)
         dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = p * (dp - delta[:, None]) * scale
+                                 preferred_element_type=jnp.float32,
+                                 precision=prec)
+        ds = p * (dp - delta) * scale
         dk_ref[0, 0] += jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+            ds, q, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32,
+            precision=prec)
 
 
 def flash_dkdv(q, k, v, dout, lse, delta, *, causal: bool, window: int = 0,
@@ -191,8 +206,8 @@ def flash_dkdv(q, k, v, dout, lse, delta, *, causal: bool, window: int = 0,
             pl.BlockSpec((1, 1, bk, D), lambda b, h, j, i: (b, h // G, j, 0)),
             pl.BlockSpec((1, 1, bk, D), lambda b, h, j, i: (b, h // G, j, 0)),
             pl.BlockSpec((1, 1, bq, D), lambda b, h, j, i: (b, h, i, 0)),
-            pl.BlockSpec((1, 1, bq), lambda b, h, j, i: (b, h, i)),
-            pl.BlockSpec((1, 1, bq), lambda b, h, j, i: (b, h, i)),
+            pl.BlockSpec((1, 1, bq, 1), lambda b, h, j, i: (b, h, i, 0)),
+            pl.BlockSpec((1, 1, bq, 1), lambda b, h, j, i: (b, h, i, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, bk, D), lambda b, h, j, i: (b, h // G, j, 0)),
@@ -224,6 +239,7 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, *,
 
     @pl.when(_tile_live(i, j, bq, bk, causal, window))
     def _compute():
+        prec = contract_precision(q_ref.dtype)
         q = q_ref[0, 0].astype(jnp.float32)
         k = k_ref[0, 0].astype(jnp.float32)
         v = v_ref[0, 0].astype(jnp.float32)
@@ -231,15 +247,18 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, *,
         lse = lse_ref[0, 0]
         delta = delta_ref[0, 0]
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
+                                preferred_element_type=jnp.float32,
+                                precision=prec) * scale
         mask = _tile_mask(i, j, bq, bk, causal, window)
-        p = jnp.exp(jnp.where(mask, s, NEG) - lse[:, None])
+        p = jnp.exp(jnp.where(mask, s, NEG) - lse)
         p = jnp.where(mask, p, 0.0)
         dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = p * (dp - delta[:, None]) * scale
+                                 preferred_element_type=jnp.float32,
+                                 precision=prec)
+        ds = p * (dp - delta) * scale
         dq_ref[0, 0] += jax.lax.dot_general(
-            ds, k, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+            ds, k, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32,
+            precision=prec)
 
 
 def flash_dq(q, k, v, dout, lse, delta, *, causal: bool, window: int = 0,
@@ -261,8 +280,8 @@ def flash_dq(q, k, v, dout, lse, delta, *, causal: bool, window: int = 0,
             pl.BlockSpec((1, 1, bk, D), lambda b, h, i, j: (b, h // G, j, 0)),
             pl.BlockSpec((1, 1, bk, D), lambda b, h, i, j: (b, h // G, j, 0)),
             pl.BlockSpec((1, 1, bq, D), lambda b, h, i, j: (b, h, i, 0)),
-            pl.BlockSpec((1, 1, bq), lambda b, h, i, j: (b, h, i)),
-            pl.BlockSpec((1, 1, bq), lambda b, h, i, j: (b, h, i)),
+            pl.BlockSpec((1, 1, bq, 1), lambda b, h, i, j: (b, h, i, 0)),
+            pl.BlockSpec((1, 1, bq, 1), lambda b, h, i, j: (b, h, i, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, bq, D), lambda b, h, i, j: (b, h, i, 0)),
         out_shape=jax.ShapeDtypeStruct((B, H, Sq, D), jnp.float32),

@@ -39,7 +39,7 @@ def _bwd(causal, window, bq, bk, res, dout):
     q, k, v, out, lse = res
     interp = _interpret_default()
     delta = jnp.sum(dout.astype(jnp.float32) * out.astype(jnp.float32),
-                    axis=-1)                                    # (B,H,Sq)
+                    axis=-1, keepdims=True)                     # (B,H,Sq,1)
     dk, dv = K.flash_dkdv(q, k, v, dout, lse, delta, causal=causal,
                           window=window, bq=bq, bk=bk, interpret=interp)
     dq = K.flash_dq(q, k, v, dout, lse, delta, causal=causal, window=window,

@@ -22,6 +22,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import contract_precision
+
 
 def _mm_kernel(a_ref, b_ref, o_ref, acc_s, *, nk: int):
     k = pl.program_id(2)
@@ -32,7 +34,8 @@ def _mm_kernel(a_ref, b_ref, o_ref, acc_s, *, nk: int):
 
     acc_s[...] += jax.lax.dot_general(
         a_ref[...].astype(jnp.float32), b_ref[...].astype(jnp.float32),
-        (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32,
+        precision=contract_precision(a_ref.dtype))
 
     @pl.when(k == nk - 1)
     def _done():

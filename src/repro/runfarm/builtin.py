@@ -4,9 +4,9 @@ golden-trace regeneration.
 Every executor is a pure function of its ``WorkUnit`` — fresh fuzzer /
 session / coverage model per call, nothing read from ambient state — so a
 unit executes bit-identically in the sequential oracle (``workers=0``)
-and in any spawned worker process.  Imports are deliberately lazy: a
-registers-layer fuzz worker never touches jax, which keeps spawn-context
-worker start-up fast.
+and in any spawned worker process, all on the CPU device
+(``execute_unit``).  Executor imports are lazy, so a worker imports only
+what its units use.
 
 Failure harvesting happens HERE, worker-side, where the failing state is
 live: a failing fuzz scenario is minimized with the existing
@@ -25,14 +25,22 @@ from repro.runfarm.units import UnitResult, WorkUnit
 
 
 def execute_unit(unit: WorkUnit) -> UnitResult:
-    """Run one unit under its registered executor (timed)."""
+    """Run one unit under its registered executor (timed) on the host CPU.
+
+    Units are host-side modeled simulation, and their float outputs enter
+    the digest.  So every lane computes on the CPU device: spawned workers
+    see only the CPU platform (runfarm/worker.py), and the
+    ``workers=0`` lane, which runs here in the parent, is placed on the CPU
+    too, even on a machine whose default device is an accelerator."""
+    import jax
     try:
         fn = EXECUTORS[unit.kind]
     except KeyError:
         raise KeyError(f"no executor for unit kind {unit.kind!r} "
                        f"(known: {sorted(EXECUTORS)})") from None
     t0 = time.perf_counter()
-    res = fn(unit)
+    with jax.default_device(jax.devices("cpu")[0]):
+        res = fn(unit)
     res.seconds = time.perf_counter() - t0
     return res
 

@@ -9,9 +9,14 @@ a fresh worker, and carries on.
 
 Spawn (not fork) keeps workers clean of the parent's jax/session state;
 ``extra_sys_path`` re-creates the parent's import path (sys.path does not
-propagate across spawn).  ``kill_after`` is the crash-recovery test hook:
-the worker SIGKILLs itself when it receives its (N+1)-th unit — after
-the assignment, before any result — the worst-case death point.
+propagate across spawn).  A worker restricts jax to the CPU before any
+computation: an accelerator belongs to one process (the parent may hold
+it), and every lane of a campaign computes on the CPU.  An environment
+variable set in ``worker_main`` would come too late, since unpickling the
+target already imported jax.
+``kill_after`` is the crash-recovery test hook: the worker SIGKILLs
+itself when it receives its (N+1)-th unit — after the assignment, before
+any result — the worst-case death point.
 """
 from __future__ import annotations
 
@@ -22,6 +27,8 @@ import sys
 
 def worker_main(worker_id: int, task_q, result_q, extra_sys_path,
                 kill_after=None) -> None:
+    import jax
+    jax.config.update("jax_platforms", "cpu")
     for p in reversed(list(extra_sys_path or [])):
         if p not in sys.path:
             sys.path.insert(0, p)
