@@ -63,11 +63,13 @@ COVERIFY_CELLS = [
 ]
 
 # Serving: a served token passes when it is the reference's argmax, or when
-# its reference logit is within |max reference logit| / 32 of that maximum.
-# bf16 keeps 8 significant bits, so one rounding moves a logit of size m by
-# up to m / 512; the tolerance allows 16 such roundings through the bf16
-# layers of the served model.
-LOGIT_TOL = 1.0 / 32
+# its reference logit is within LOGIT_TOL standard deviations of that
+# maximum, the deviation taken over the vocabulary at that position (a
+# common offset of the logits does not move it).  bf16 rounding in the
+# served model moves the logits by a small fraction of one deviation; a
+# token chosen from the wrong context sits several below the maximum
+# (tests/test_chip_compile.py plants such a fault and expects a failure).
+LOGIT_TOL = 0.25
 
 
 def _coverify_firmware(fb, op, backend, *, block, **config):
@@ -132,29 +134,28 @@ def _reference_fn(cfg):
         logits = lm_logits(cfg, params, x, None)             # (R, N, V)
         top = jnp.max(logits, axis=-1)
         at = jnp.take_along_axis(logits, served[..., None], axis=-1)[..., 0]
-        return jnp.argmax(logits, axis=-1), top, at
+        return jnp.argmax(logits, axis=-1), top, at, jnp.std(logits, axis=-1)
 
     return jax.jit(ref)
 
 
 def serve_phase(cfg, *, n_requests: int = 8, max_len: int = 512,
-                max_prompt: int = 200, new_tokens=(8, 32),
+                prompt_lens=(16, 200), new_tokens=(8, 32),
                 seed: int = 0) -> dict:
     """Serve ``n_requests`` through the CSR protocol and check every token
     against the float32 reference; raise unless every check holds.
 
-    Prompt lengths are whole prefill buckets (multiples of the engine's
-    ``prompt_pad``): the engine's prefill attends to left padding, which
-    the unpadded reference does not see.
+    Prompt lengths and new-token counts are drawn uniformly from the
+    inclusive ranges ``prompt_lens`` and ``new_tokens``, so most prompts
+    are left-padded to the engine's prefill bucket.
     """
     params = init_params(cfg, jax.random.PRNGKey(seed), dtype=jnp.bfloat16)
     eng = ServingEngine(cfg, params, batching="continuous", max_slots=4,
                         max_len=max_len)
     rng = np.random.default_rng(seed)
-    pad = eng.prompt_pad
     t0 = time.perf_counter()
     for rid in range(n_requests):
-        ln = pad * int(rng.integers(1, max_prompt // pad + 1))
+        ln = int(rng.integers(prompt_lens[0], prompt_lens[1] + 1))
         eng.mem.buffers["prompt_in"].array[:ln] = \
             rng.integers(0, cfg.vocab_size, ln)
         eng.csr.fb_write_32(eng.csr.addr_of("SUBMIT_ID"), rid)
@@ -194,14 +195,14 @@ def serve_phase(cfg, *, n_requests: int = 8, max_len: int = 512,
     ref_params = jax.tree.map(lambda a: a.astype(jnp.float32), params)
     t0 = time.perf_counter()
     with jax.default_matmul_precision("float32"):
-        arg, top, at = jax.device_get(_reference_fn(cfg)(
+        arg, top, at, spread = jax.device_get(_reference_fn(cfg)(
             ref_params, jnp.asarray(tokens), jnp.asarray(pos),
             jnp.asarray(served)))
     ref_s = time.perf_counter() - t0
     live = np.arange(n_new)[None, :] < np.asarray(
         [r.max_new_tokens for r in reqs])[:, None]
     exact = live & (arg == served)
-    gap = (top - at) / np.maximum(np.abs(top), 1e-30)
+    gap = (top - at) / spread
     tolerated = live & ~exact & (gap <= LOGIT_TOL)
     bad = live & ~exact & ~tolerated
     if bad.any():
@@ -209,10 +210,11 @@ def serve_phase(cfg, *, n_requests: int = 8, max_len: int = 512,
         raise RuntimeError(
             f"{int(bad.sum())} served token(s) disagree with the float32 "
             f"reference; first: request {i} token {j} served {served[i, j]}"
-            f", reference argmax {arg[i, j]}, relative logit gap "
-            f"{gap[i, j]:.4g} > {LOGIT_TOL:.4g}")
+            f", reference argmax {arg[i, j]}, logit gap {gap[i, j]:.4g} "
+            f"reference std > {LOGIT_TOL:.4g}")
     return {"requests": n_requests, "tokens": int(live.sum()),
             "exact": int(exact.sum()), "tolerated": int(tolerated.sum()),
+            "padded": sum(len(r.prompt) % eng.prompt_pad != 0 for r in reqs),
             "max_gap": float(gap[live].max()), "serve_s": serve_s,
             "reference_s": ref_s}
 
@@ -231,9 +233,10 @@ def main() -> int:
           f"(tol {COVERIFY_TOL:g}), compiled tier is tpu_custom_call; "
           f"{cv['wall_s']:.1f} s wall incl. compile", flush=True)
     sv = serve_phase(get_config("llama3.2-1b"))
-    print(f"serve: llama3.2-1b, {sv['requests']} requests, {sv['tokens']} "
-          f"tokens: {sv['exact']} reference argmax, {sv['tolerated']} within "
-          f"tolerance (max relative gap {sv['max_gap']:.4g}); "
+    print(f"serve: llama3.2-1b, {sv['requests']} requests ({sv['padded']} "
+          f"left-padded), {sv['tokens']} tokens: {sv['exact']} reference "
+          f"argmax, {sv['tolerated']} within tolerance (max gap "
+          f"{sv['max_gap']:.4g} std, tol {LOGIT_TOL:g}); "
           f"{sv['serve_s']:.1f} s serving, {sv['reference_s']:.1f} s "
           "reference, incl. compile", flush=True)
     print(json.dumps({"ok": True, "device": {

@@ -63,7 +63,7 @@ def flash_backends(bq: int = 32, bk: int = 32, causal: bool = True,
 
 
 def flash_chip_backends(bq: int, bk: int, causal: bool = True) -> dict:
-    """oracle/interpret/compiled backend table for a TPU.
+    """``flash_backends(bq, bk, causal)`` for a TPU.
 
     compiled = the Pallas forward kernel compiled for the chip
     (``interpret=False``), jitted once; its ``kernel`` attribute is that
@@ -71,28 +71,20 @@ def flash_chip_backends(bq: int, bk: int, causal: bool = True) -> dict:
     runs at float32 matmul precision: a TPU's default precision for an f32
     dot is one bf16 pass.  Needs a TPU.
     """
-    def fwd(q, k, v, interpret):
-        out, _ = K.flash_fwd(q, k, v, causal=causal, window=0, bq=bq, bk=bk,
-                             interpret=interpret)
-        return out
+    table = flash_backends(bq, bk, causal)
+    ref = table["oracle"]
+    kernel = jax.jit(lambda q, k, v: K.flash_fwd(
+        q, k, v, causal=causal, window=0, bq=bq, bk=bk, interpret=False)[0])
 
     def oracle(q, k, v):
         with jax.default_matmul_precision("float32"):
-            return np.asarray(R.attention_ref(
-                jnp.asarray(q), jnp.asarray(k), jnp.asarray(v),
-                causal=causal))
-
-    jit_interp = jax.jit(lambda q, k, v: fwd(q, k, v, True))
-    kernel = jax.jit(lambda q, k, v: fwd(q, k, v, False))
+            return ref(q, k, v)
 
     def compiled(q, k, v):
         return np.asarray(kernel(jnp.asarray(q), jnp.asarray(k),
                                  jnp.asarray(v)))
     compiled.kernel = kernel
-    return dict(oracle=oracle,
-                interpret=lambda q, k, v: np.asarray(jit_interp(
-                    jnp.asarray(q), jnp.asarray(k), jnp.asarray(v))),
-                compiled=compiled)
+    return dict(table, oracle=oracle, compiled=compiled)
 
 
 def flash_firmware(fb, op, backend, *, batch=1, heads=8, seq=64, dim=16,

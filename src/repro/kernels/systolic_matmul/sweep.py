@@ -84,7 +84,7 @@ def matmul_backends(tile: int = 32, jit: bool = True) -> dict:
 
 
 def matmul_chip_backends(tile: int) -> dict:
-    """oracle/interpret/compiled backend table for a TPU.
+    """``matmul_backends(tile)`` for a TPU.
 
     compiled = the Pallas kernel compiled for the chip (``interpret=False``),
     jitted once; its ``kernel`` attribute is that jitted function, so a
@@ -92,20 +92,16 @@ def matmul_chip_backends(tile: int) -> dict:
     matmul precision: a TPU's default precision for an f32 dot is one bf16
     pass.  Needs a TPU and (8, 128)-aligned tiles.
     """
-    def oracle(x, y):
-        with jax.default_matmul_precision("float32"):
-            return np.asarray(mm_ref.matmul_ref(jnp.asarray(x),
-                                                jnp.asarray(y)))
-
-    jit_interp = jax.jit(lambda x, y: mm_kernel(
-        x, y, bm=tile, bn=tile, bk=tile, interpret=True))
+    table = matmul_backends(tile)
+    ref = table["oracle"]
     kernel = jax.jit(lambda x, y: mm_kernel(
         x, y, bm=tile, bn=tile, bk=tile, interpret=False))
+
+    def oracle(x, y):
+        with jax.default_matmul_precision("float32"):
+            return ref(x, y)
 
     def compiled(x, y):
         return np.asarray(kernel(jnp.asarray(x), jnp.asarray(y)))
     compiled.kernel = kernel
-    return dict(oracle=oracle,
-                interpret=lambda x, y: np.asarray(jit_interp(
-                    jnp.asarray(x), jnp.asarray(y))),
-                compiled=compiled)
+    return dict(table, oracle=oracle, compiled=compiled)

@@ -232,8 +232,9 @@ def _qkv(cfg, w, x, pos):
     return q, k, v
 
 
-def attn_block(cfg, flags: RunFlags, ctx, w, ln, x, pos, *, window=0,
-               return_kv=False):
+def attn_block(cfg, flags: RunFlags, ctx, w, ln, x, pos, *, kv_pos,
+               window=0, return_kv=False):
+    """``kv_pos`` is ``pos`` with -1 on the keys no query may see."""
     h = layers.rms_norm(x, ln, cfg.norm_eps)
     q, k, v = _qkv(cfg, w, h, pos)
     spec = AttnSpec(causal=cfg.causal, window=window, q_chunk=flags.q_chunk,
@@ -241,7 +242,7 @@ def attn_block(cfg, flags: RunFlags, ctx, w, ln, x, pos, *, window=0,
                     skip_masked_tiles=flags.skip_masked_tiles,
                     positions_are_arange=True)
     o = attention(q, k, v, impl=flags.attn_impl, spec=spec, q_pos=pos,
-                  kv_pos=pos)
+                  kv_pos=kv_pos)
     B, S, _ = x.shape
     out = x + checkpoint_name(
         o.reshape(B, S, cfg.d_q) @ w["wo"], "attn_out")
@@ -323,7 +324,11 @@ def _maybe_remat(fn, flags: RunFlags):
 
 def forward(cfg: ModelConfig, params, batch: dict, flags: RunFlags,
             ctx: Optional[ShardCtx], *, collect_cache: bool = False):
-    """Returns (hidden (B,S,d), aux_losses, cache_parts or None)."""
+    """Returns (hidden (B,S,d), aux_losses, cache_parts or None).
+
+    ``batch["n_pad"]`` (B,), if given, counts row b's leading pad tokens:
+    their keys get position -1, so no attention query sees them.
+    """
     fam = cfg.family
     cdt = jnp.dtype(flags.compute_dtype)
     if cfg.frontend == "frames":
@@ -336,6 +341,9 @@ def forward(cfg: ModelConfig, params, batch: dict, flags: RunFlags,
         B, S = ids.shape
         pos = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
         x = embed_lookup(cfg, params, ids, ctx).astype(cdt)
+    kv_pos = pos
+    if "n_pad" in batch:
+        kv_pos = jnp.where(pos < batch["n_pad"][:, None], -1, pos)
     seq_axis = "model" if flags.sequence_parallel else None
     x = _constrain(x, ctx, ctx.data_spec if ctx else None, seq_axis, None)
 
@@ -350,9 +358,10 @@ def forward(cfg: ModelConfig, params, batch: dict, flags: RunFlags,
             x, aux = carry
             if collect_cache:
                 x, (k, v) = attn_block(cfg, flags, ctx, wl["attn"], wl["ln1"],
-                                       x, pos, return_kv=True)
+                                       x, pos, kv_pos=kv_pos, return_kv=True)
             else:
-                x = attn_block(cfg, flags, ctx, wl["attn"], wl["ln1"], x, pos)
+                x = attn_block(cfg, flags, ctx, wl["attn"], wl["ln1"], x, pos,
+                               kv_pos=kv_pos)
             if has_moe:
                 x, a = moe_block(cfg, flags, ctx, wl["moe"], wl["ln2"], x, None)
                 aux = aux + a
@@ -380,10 +389,12 @@ def forward(cfg: ModelConfig, params, batch: dict, flags: RunFlags,
             def inner(x, wi):
                 if collect_cache:
                     x, (k, v) = attn_block(cfg, flags, ctx, wi["attn"],
-                                           wi["ln1"], x, pos, return_kv=True)
+                                           wi["ln1"], x, pos, kv_pos=kv_pos,
+                                           return_kv=True)
                     x = mlp_block(cfg, wi["mlp"], wi["ln2"], x)
                     return x, (k, v)
-                x = attn_block(cfg, flags, ctx, wi["attn"], wi["ln1"], x, pos)
+                x = attn_block(cfg, flags, ctx, wi["attn"], wi["ln1"], x, pos,
+                               kv_pos=kv_pos)
                 x = mlp_block(cfg, wi["mlp"], wi["ln2"], x)
                 return x, None
 
@@ -435,11 +446,11 @@ def forward(cfg: ModelConfig, params, batch: dict, flags: RunFlags,
                 {"w": wl["mamba"], "ln": wl["mamba_ln"]})
             if collect_cache:
                 x, (k, v) = attn_block(cfg, flags, ctx, shared["attn"],
-                                       shared["ln1"], x, pos,
+                                       shared["ln1"], x, pos, kv_pos=kv_pos,
                                        window=cfg.attn_window, return_kv=True)
             else:
                 x = attn_block(cfg, flags, ctx, shared["attn"], shared["ln1"],
-                               x, pos, window=cfg.attn_window)
+                               x, pos, kv_pos=kv_pos, window=cfg.attn_window)
             x = mlp_block(cfg, shared["mlp"], shared["ln2"], x)
             if collect_cache:
                 W = min(cfg.attn_window or x.shape[1], x.shape[1])
@@ -479,6 +490,8 @@ def forward(cfg: ModelConfig, params, batch: dict, flags: RunFlags,
     else:
         raise ValueError(fam)
 
+    if collect_cache:
+        cache["kv_pos"] = kv_pos
     return x, aux, cache
 
 
@@ -514,17 +527,19 @@ def make_prefill_fn(cfg: ModelConfig, flags: RunFlags, ctx: Optional[ShardCtx],
 
 
 def _grow_cache(cfg, parts, B, S, max_len):
-    """Pad prefill-collected cache parts out to max_len and add bookkeeping."""
+    """Pad prefill-collected cache parts out to max_len and add bookkeeping.
+
+    ``parts["kv_pos"]`` (B, S) holds the prefill's key positions, -1 on
+    left padding, so decode keeps the pad keys masked."""
     fam = cfg.family
     pos = jnp.full((B,), S, jnp.int32)                        # next position
-    out = dict(parts or {})
+    out = dict(parts)
+    kv_pos = out.pop("kv_pos")
     if "k" in out:                                            # dense/moe/vlm/audio
         pad = max_len - S
         out["k"] = jnp.pad(out["k"], ((0, 0), (0, 0), (0, pad), (0, 0), (0, 0)))
         out["v"] = jnp.pad(out["v"], ((0, 0), (0, 0), (0, pad), (0, 0), (0, 0)))
-        out["kv_pos"] = jnp.concatenate([
-            jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S)),
-            jnp.full((B, pad), -1, jnp.int32)], axis=1)
+        out["kv_pos"] = jnp.pad(kv_pos, ((0, 0), (0, pad)), constant_values=-1)
     if fam == "hybrid":
         W = out["win_k"].shape[2]
         # Align window cache to the decode ring-slot convention slot = pos % W:
@@ -534,8 +549,7 @@ def _grow_cache(cfg, parts, B, S, max_len):
         out["win_k"] = jnp.roll(out["win_k"], shift, axis=2)
         out["win_v"] = jnp.roll(out["win_v"], shift, axis=2)
         out["win_pos"] = jnp.roll(jnp.broadcast_to(
-            jnp.arange(S - W, S, dtype=jnp.int32), out["win_k"].shape[:3]
-        ).astype(jnp.int32), shift, axis=2)
+            kv_pos[:, S - W:], out["win_k"].shape[:3]), shift, axis=2)
     out["pos"] = pos
     return out
 

@@ -39,7 +39,9 @@ def execute_unit(unit: WorkUnit) -> UnitResult:
         raise KeyError(f"no executor for unit kind {unit.kind!r} "
                        f"(known: {sorted(EXECUTORS)})") from None
     t0 = time.perf_counter()
-    with jax.default_device(jax.devices("cpu")[0]):
+    # a platform name, not a device: jax resolves it only when a unit
+    # dispatches a computation, so a unit that never does starts no backend
+    with jax.default_device("cpu"):
         res = fn(unit)
     res.seconds = time.perf_counter() - t0
     return res

@@ -287,21 +287,21 @@ class ServingEngine:
         """Prefill ``req`` into ``slot``: bucket-padded prefill, cache
         insert, first-token emit (shared by the storm and continuous
         schedulers; bit-exact with the legacy tick)."""
-        # Left-pad to the prefill bucket; pad keys are masked out below.
-        # RoPE scores depend only on position deltas, so the constant
-        # offset is exact for attention families; for SSM/hybrid the
-        # leading pad tokens perturb the state unless the prompt length
-        # is already a bucket multiple (documented in the class doc).
+        # Left-pad to the prefill bucket.  The prefill gives the pad keys
+        # position -1, so neither it nor any later decode step attends to
+        # them; RoPE scores depend only on position deltas, so the
+        # constant offset of the real tokens is exact for attention
+        # families.  For SSM/hybrid the leading pad tokens still perturb
+        # the recurrent state unless the prompt length is already a bucket
+        # multiple.
         pl = self._pad_len(len(req.prompt))
         pad_n = pl - len(req.prompt)
         toks = np.zeros((1, pl), np.int32)
         toks[0, pad_n:] = req.prompt
-        logits, single = self._prefill(
-            self.params, self._batchify({"tokens": jnp.asarray(toks)}))
+        logits, single = self._prefill(self.params, self._batchify({
+            "tokens": jnp.asarray(toks),
+            "n_pad": jnp.asarray([pad_n], jnp.int32)}))
         self.cache = cache_insert(self.cache, single, slot)
-        if pad_n and "kv_pos" in self.cache:
-            self.cache["kv_pos"] = \
-                self.cache["kv_pos"].at[slot, :pad_n].set(-1)
         self.slots[slot] = req
         first = int(jnp.argmax(logits[0]))
         req.out_tokens.append(first)

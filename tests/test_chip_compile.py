@@ -17,7 +17,9 @@ from jax.sharding import SingleDeviceSharding
 import chip_smoke
 from repro.configs import get_config, smoke
 from repro.kernels.flash_attention import kernel as FK
+from repro.kernels.flash_attention.sweep import flash_chip_backends
 from repro.kernels.systolic_matmul.kernel import matmul
+from repro.kernels.systolic_matmul.sweep import matmul_chip_backends
 from repro.models import init_params
 from repro.models.transformer import RunFlags, make_prefill_fn
 
@@ -68,15 +70,51 @@ def test_flash_compiles_for_v5e(one_chip, kernel, dtype):
                           interpret=False), *args)
 
 
+@pytest.mark.parametrize("kind,block,config",
+                         [cell[:3] for cell in chip_smoke.COVERIFY_CELLS])
+def test_coverify_compiled_tier_compiles_for_v5e(one_chip, kind, block,
+                                                 config):
+    """Each chip_smoke co-verify cell's compiled tier, taken from the
+    backend table the smoke registers, is a Pallas kernel for the chip."""
+    table = (matmul_chip_backends(block) if kind == "matmul"
+             else flash_chip_backends(block, block))
+    args = [jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+            for a in chip_smoke._kernel_inputs(kind, config)]
+    text = table["compiled"].kernel.lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+
+
+SMOKE_SERVE = dict(n_requests=3, max_len=128, prompt_lens=(16, 48),
+                   new_tokens=(4, 8))
+
+
 def test_serve_phase_on_cpu():
     """chip_smoke's serve phase at smoke() size: every request completes
-    through the CSR protocol and agrees with the float32 reference."""
+    through the CSR protocol and agrees with the float32 reference,
+    left-padded prompts included."""
     stats = chip_smoke.serve_phase(smoke(get_config("llama3.2-1b")),
-                                   n_requests=3, max_len=128, max_prompt=48,
-                                   new_tokens=(4, 8))
+                                   **SMOKE_SERVE)
     assert stats["requests"] == 3
+    assert stats["padded"] > 0
     assert stats["exact"] + stats["tolerated"] == stats["tokens"]
     assert 3 * 4 <= stats["tokens"] <= 3 * 8
+
+
+def test_serve_phase_rejects_attending_to_padding(monkeypatch):
+    """Planted fault: a prefill that attends to its left-pad keys serves
+    tokens the unpadded reference would not give, and the serve phase's
+    logit-gap rule refuses them."""
+    from repro.serving.engine import ServingEngine
+    batchify = ServingEngine._batchify
+
+    def unmasked(self, batch):
+        batch.pop("n_pad")
+        return batchify(self, batch)
+
+    monkeypatch.setattr(ServingEngine, "_batchify", unmasked)
+    with pytest.raises(RuntimeError, match="disagree with the float32"):
+        chip_smoke.serve_phase(smoke(get_config("llama3.2-1b")),
+                               **SMOKE_SERVE)
 
 
 def test_reference_is_prefill_at_every_position():
@@ -87,8 +125,8 @@ def test_reference_is_prefill_at_every_position():
     tokens = jax.random.randint(jax.random.PRNGKey(2), (1, 24), 0,
                                 cfg.vocab_size)
     pos = jnp.asarray([[5, 11, 23]])
-    arg, top, _ = chip_smoke._reference_fn(cfg)(params, tokens, pos,
-                                                jnp.zeros_like(pos))
+    arg, top, _, _ = chip_smoke._reference_fn(cfg)(params, tokens, pos,
+                                                   jnp.zeros_like(pos))
     prefill = make_prefill_fn(cfg, RunFlags(compute_dtype="float32"), None,
                               max_len=24)
     for j, p in enumerate(np.asarray(pos[0])):
