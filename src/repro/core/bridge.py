@@ -36,6 +36,7 @@ from repro.core.congestion import (CongestionConfig, CongestionResult,
 from repro.core.counters import (CounterBank, CounterSpec,
                                  register_link_counters)
 from repro.core.registers import RegisterFile
+from repro.core.spans import span
 from repro.core.transactions import (BurstBatch, OpMark, Transaction,
                                      TransactionLog, record_mark)
 
@@ -120,7 +121,9 @@ class MemoryBridge:
                 f"buffer {name!r} already allocated at "
                 f"{self.buffers[name].addr:#x}; re-alloc would silently "
                 f"shadow it (free-list reuse is not modeled)")
-        arr = np.zeros(shape, dtype)
+        with span("fb.mem.alloc") as s:
+            arr = np.zeros(shape, dtype)
+            s.set(bytes=arr.nbytes)
         size = -(-arr.nbytes // self.PAGE) * self.PAGE
         buf = Buffer(name, self._next, arr)
         self._next += size
@@ -130,12 +133,14 @@ class MemoryBridge:
     # Firmware-side access: plain numpy (paper: dereferencing C pointers).
     def host_write(self, name: str, data) -> None:
         buf = self.buffers[name]
-        arr = np.asarray(data, buf.array.dtype)
-        if arr.shape != buf.array.shape:
-            raise ValueError(
-                f"host_write to {name!r}: data shape {arr.shape} != buffer "
-                f"shape {buf.array.shape} (refusing silent broadcast)")
-        np.copyto(buf.array, arr)
+        with span("fb.mem.host_write", bytes=buf.nbytes):
+            arr = np.asarray(data, buf.array.dtype)
+            if arr.shape != buf.array.shape:
+                raise ValueError(
+                    f"host_write to {name!r}: data shape {arr.shape} != "
+                    f"buffer shape {buf.array.shape} (refusing silent "
+                    f"broadcast)")
+            np.copyto(buf.array, arr)
 
     def host_read(self, name: str) -> np.ndarray:
         return self.buffers[name].array.copy()
@@ -154,10 +159,11 @@ class MemoryBridge:
         applying any fault-plan perturbation first."""
         if self.fault_plan is not None:
             batch = self.fault_plan.perturb_batch(batch, self.log)
-        if self.link is not None:
-            self.time = self.link.submit_batch(batch, self.log)
-        else:
-            self.time = self._fast_clock(batch, self.time)
+        with span("fb.link", bursts=len(batch)):
+            if self.link is not None:
+                self.time = self.link.submit_batch(batch, self.log)
+            else:
+                self.time = self._fast_clock(batch, self.time)
         self.counters.tick(self.time)
 
     def _fast_clock(self, batch: BurstBatch, t: float) -> float:
@@ -185,25 +191,28 @@ class MemoryBridge:
         clean data while the protocol path is exercised.
         """
         buf = self.buffers[name]
-        self._submit(self._dev_bursts(buf, "read", engine, name))
-        data = buf.array.copy()
-        if (self.fault_plan is not None
-                and self.fault_plan.flip_read(data, name, self.log)):
-            # corrupted transfer detected against ECC: audited retry
+        with span("fb.mem.dev_read", bytes=buf.nbytes):
             self._submit(self._dev_bursts(buf, "read", engine, name))
             data = buf.array.copy()
+            if (self.fault_plan is not None
+                    and self.fault_plan.flip_read(data, name, self.log)):
+                # corrupted transfer detected against ECC: audited retry
+                self._submit(self._dev_bursts(buf, "read", engine, name))
+                data = buf.array.copy()
         return data
 
     def dev_write(self, name: str, data, engine: str = "dma") -> None:
         """Accelerator-side write: transaction-logged, congestion-timed."""
         buf = self.buffers[name]
-        arr = np.asarray(data, buf.array.dtype)
-        if arr.shape != buf.array.shape:
-            raise ValueError(
-                f"dev_write to {name!r}: data shape {arr.shape} != buffer "
-                f"shape {buf.array.shape} (refusing silent broadcast)")
-        self._submit(self._dev_bursts(buf, "write", engine, name))
-        np.copyto(buf.array, arr)
+        with span("fb.mem.dev_write", bytes=buf.nbytes):
+            arr = np.asarray(data, buf.array.dtype)
+            if arr.shape != buf.array.shape:
+                raise ValueError(
+                    f"dev_write to {name!r}: data shape {arr.shape} != "
+                    f"buffer shape {buf.array.shape} (refusing silent "
+                    f"broadcast)")
+            self._submit(self._dev_bursts(buf, "write", engine, name))
+            np.copyto(buf.array, arr)
 
     def log_burst_list(self, txs: List[Tuple[str, str, int, int]],
                        base_time: Optional[float] = None) -> None:
@@ -215,15 +224,11 @@ class MemoryBridge:
         bandwidth exactly as the paper's DMA VIPs do on the AXI fabric
         (Fig. 8) — and ``self.time`` advances to the batch makespan.
         """
-        t = self.time if base_time is None else base_time
-        batch = BurstBatch.from_tuples(t, txs)
-        if self.fault_plan is not None:
-            batch = self.fault_plan.perturb_batch(batch, self.log)
-        if self.link is not None:
-            self.time = self.link.submit_batch(batch, self.log)
-        else:
-            self.time = self._fast_clock(batch, t)
-        self.counters.tick(self.time)
+        if base_time is not None:
+            self.time = base_time     # the batch and the fast clock start here
+        with span("fb.launch.bursts", bursts=len(txs)):
+            batch = BurstBatch.from_tuples(self.time, txs)
+        self._submit(batch)
 
     def congestion_stats(self) -> Optional[CongestionResult]:
         """Fig. 8 statistics accumulated by the online link so far
@@ -314,7 +319,8 @@ class FireBridge:
         so per-engine stalls are produced by the launch itself (Fig. 8).
         """
         assert backend in self.BACKENDS, backend
-        with self.mem.mark(f"{op}@{backend}", engine):
+        with span("fb.launch", op=op, backend=backend), \
+                self.mem.mark(f"{op}@{backend}", engine):
             self._launch(op, backend, in_bufs, out_bufs, engine,
                          burst_list, kw)
 
@@ -325,8 +331,11 @@ class FireBridge:
         args = [self.mem.dev_read(n, engine=f"{engine}_rd") for n in in_bufs]
         bl = burst_list or fns["burst_list"]
         if bl is not None:
-            self.mem.log_burst_list(bl())
-        outs = fns[backend](*args, **kw)
+            with span("fb.launch.bursts"):          # building the list
+                txs = bl()
+            self.mem.log_burst_list(txs)
+        with span("fb.backend", op=op, backend=backend):
+            outs = fns[backend](*args, **kw)
         if not isinstance(outs, (tuple, list)):
             outs = (outs,)
         if len(outs) != len(out_bufs):

@@ -16,7 +16,6 @@ reproduction (benchmarks/bench_debug_iteration.py).
 from __future__ import annotations
 
 import dataclasses
-import time
 from typing import Callable, Dict, List, Optional
 
 import numpy as np
@@ -24,6 +23,7 @@ import numpy as np
 from repro.core.bridge import FireBridge
 from repro.core.congestion import CongestionConfig, CongestionResult
 from repro.core.equivalence import EquivalenceReport, compare_outputs
+from repro.core.spans import span
 from repro.core.transactions import TransactionLog
 
 
@@ -54,24 +54,30 @@ def coverify(firmware: Callable[[FireBridge, str], None],
     With `congestion` set, each bridge runs with the online link model
     (paper §IV-C) so stalls/makespan are produced during the launch; the
     returned `congestion` field is the last backend's live statistics.
+
+    The call is one ``fb.sweep`` span (core/spans.py) holding each
+    backend's ``fb.firmware`` span and the ``fb.sweep.compare``.
     """
     final_state: Dict[str, dict] = {}
     iter_s: Dict[str, float] = {}
     last_bridge: Optional[FireBridge] = None
     violations: List[str] = []
 
-    for be in backends:
-        fb = FireBridge(congestion=congestion)
-        for name, fns in ops.items():
-            fb.register_op(name, **fns)
-        t0 = time.perf_counter()
-        firmware(fb, be)
-        iter_s[be] = time.perf_counter() - t0
-        final_state[be] = {n: b.array.copy() for n, b in fb.mem.buffers.items()}
-        violations.extend(f"[{be}] {v}" for v in fb.log.violations)
-        last_bridge = fb
+    with span("fb.sweep", cells=len(backends)):
+        for be in backends:
+            fb = FireBridge(congestion=congestion)
+            for name, fns in ops.items():
+                fb.register_op(name, **fns)
+            with span("fb.firmware") as fw:
+                firmware(fb, be)
+            iter_s[be] = fw.seconds
+            final_state[be] = {n: b.array.copy()
+                               for n, b in fb.mem.buffers.items()}
+            violations.extend(f"[{be}] {v}" for v in fb.log.violations)
+            last_bridge = fb
 
-    eq = compare_outputs(final_state, tol=tol)
+        with span("fb.sweep.compare", group="coverify"):
+            eq = compare_outputs(final_state, tol=tol)
 
     cong = None
     if congestion is not None and last_bridge is not None:

@@ -26,8 +26,8 @@ sequential per-op loop on a >=8-cell sweep (the Fig. 5 batched lane).
 from __future__ import annotations
 
 import dataclasses
+import functools
 import hashlib
-import time
 from concurrent.futures import ThreadPoolExecutor
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
@@ -39,6 +39,10 @@ from repro.core.coverage import CoverageModel
 from repro.core.equivalence import EquivalenceReport, compare_outputs
 from repro.core.fabric import FabricCluster
 from repro.core.fuzz import FaultEvent, FaultPlan
+from repro.core.spans import span
+
+# the phases of CoVerifySession.run, as SweepReport.phase_seconds keys
+PHASES = ("cells", "precheck", "compare", "bisect")
 
 
 def _freeze(v: Any) -> Tuple:
@@ -77,6 +81,17 @@ def _freeze(v: Any) -> Tuple:
 def _config_key(config: Dict[str, Any]) -> Tuple:
     """Hashable identity of a cell config (for cross-backend grouping)."""
     return tuple(sorted((k, _freeze(v)) for k, v in config.items()))
+
+
+def _nbytes(outputs: Dict[str, np.ndarray]) -> int:
+    return sum(a.nbytes for a in outputs.values())
+
+
+def _compared_elems(outs: Dict[str, Dict[str, np.ndarray]]) -> int:
+    """Elements one group's diff takes in: every other member's buffers
+    against the first member's."""
+    first = next(iter(outs.values()))
+    return (len(outs) - 1) * sum(int(np.size(a)) for a in first.values())
 
 
 @dataclasses.dataclass
@@ -219,6 +234,11 @@ class SweepReport:
     sweep hands back the first divergent transaction + surrounding device
     state instead of just "these backends disagree" (the time-travel debug
     loop, core/replay.py).
+
+    ``wall_seconds`` is the host time of the cell phase (the pool's
+    dispatch and join); ``phase_seconds`` splits the whole run into
+    ``cells``, ``precheck`` (counter digests), ``compare`` (the output
+    diff) and ``bisect``, each the duration of its ``fb.sweep.*`` span.
     """
     cells: List[CellResult]
     equivalence: Dict[str, EquivalenceReport]
@@ -234,6 +254,8 @@ class SweepReport:
     # escalates into the replay-bisection lane
     counter_mismatches: Dict[str, Any] = dataclasses.field(
         default_factory=dict)
+    phase_seconds: Dict[str, float] = dataclasses.field(
+        default_factory=dict)
 
     @property
     def passed(self) -> bool:
@@ -248,6 +270,8 @@ class SweepReport:
             "passed": self.passed,
             "wall_seconds": round(self.wall_seconds, 3),
             "cell_seconds_sum": round(sum(r.seconds for r in self.cells), 3),
+            "phase_seconds": {k: round(v, 3)
+                              for k, v in self.phase_seconds.items()},
             "failures": [g for g, e in self.equivalence.items()
                          if not e.passed] +
                         [r.cell.label for r in self.cells if r.error],
@@ -383,6 +407,7 @@ class CoVerifySession:
         self.link_config = link_config
         self._ops: Dict[str, Dict[str, Any]] = {}
         self.cells: List[SweepCell] = []
+        self._sweeps = 0                  # run() calls, the spans' ``sweep``
         # open-loop serving lane (register_serving/add_serving_cell)
         self._serving_factory: Optional[Callable[..., Any]] = None
 
@@ -458,43 +483,62 @@ class CoVerifySession:
                 for t in (topologies if n > 1 else (None,))]
 
     # ----------------------------------------------------------- execute
-    def _run_cell(self, cell: SweepCell) -> CellResult:
-        if cell.serving is not None:
-            return self._run_serving_cell(cell)
-        # each cell forks its own child plan keyed by the cell label, so
-        # thread-pool scheduling order cannot perturb the fault stream
-        plan = (cell.fault_plan.fork(cell.label)
-                if cell.fault_plan is not None else None)
-        if cell.devices > 1 or self.fabric_firmware is not None:
-            return self._run_fabric_cell(cell, plan)
+    def _run_cell(self, cell: SweepCell, sweep: int = 0) -> CellResult:
+        """One cell inside an ``fb.cell`` span, which carries the run
+        number of its sweep: a pool thread's spans join the caller's."""
+        with span("fb.cell", sweep=sweep, cell=cell.label):
+            if cell.serving is not None:
+                return self._run_serving_cell(cell)
+            # each cell forks its own child plan keyed by the cell label,
+            # so thread-pool scheduling order cannot perturb the fault
+            # stream
+            plan = (cell.fault_plan.fork(cell.label)
+                    if cell.fault_plan is not None else None)
+            if cell.devices > 1 or self.fabric_firmware is not None:
+                return self._run_fabric_cell(cell, plan)
+            return self._run_bridge_cell(cell, plan)
+
+    @staticmethod
+    def _run_firmware(fw: Callable[..., None], target: Any, cell: SweepCell
+                      ) -> Tuple[float, Optional[str]]:
+        """Run the firmware in an ``fb.firmware`` span: (its seconds, the
+        error it raised or None)."""
+        err: Optional[str] = None
+        with span("fb.firmware") as s:
+            try:
+                fw(target, cell.op, cell.backend, **cell.config)
+            except Exception as e:        # cell failure must not kill sweep
+                err = f"{type(e).__name__}: {e}"
+        return s.seconds, err
+
+    def _run_bridge_cell(self, cell: SweepCell,
+                         plan: Optional[FaultPlan]) -> CellResult:
+        """One single-device cell on a fresh FireBridge."""
         cov = CoverageModel() if self.coverage is not None else None
         fb = FireBridge(congestion=cell.congestion, fault_plan=plan,
                         profile=self.profile)
         fb.register_op(cell.op, **self._ops[cell.op])
-        t0 = time.perf_counter()
-        err: Optional[str] = None
-        try:
-            self.firmware(fb, cell.op, cell.backend, **cell.config)
-        except Exception as e:            # cell failure must not kill sweep
-            err = f"{type(e).__name__}: {e}"
-        dt = time.perf_counter() - t0
-        if cov is not None:
-            self._feed_coverage(cov, fb.log, plan)
-        return CellResult(
-            cell=cell,
-            outputs={n: b.array.copy() for n, b in fb.mem.buffers.items()},
-            seconds=dt,
-            bridge_time=fb.mem.time,
-            congestion=fb.congestion_stats(),
-            violations=list(fb.log.violations),
-            error=err,
-            faults=list(plan.events) if plan is not None else [],
-            profile=fb.profiler(cell.label) if self.profile else None,
-            coverage=cov,
-            counters=(self._cell_counters(
-                fb, cell, cell.label if plan is not None else None)
-                if err is None else None),
-        )
+        dt, err = self._run_firmware(self.firmware, fb, cell)
+        with span("fb.cell.collect") as col:
+            if cov is not None:
+                self._feed_coverage(cov, fb.log, plan)
+            outputs = {n: b.array.copy() for n, b in fb.mem.buffers.items()}
+            col.set(bytes=_nbytes(outputs))
+            return CellResult(
+                cell=cell,
+                outputs=outputs,
+                seconds=dt,
+                bridge_time=fb.mem.time,
+                congestion=fb.congestion_stats(),
+                violations=list(fb.log.violations),
+                error=err,
+                faults=list(plan.events) if plan is not None else [],
+                profile=fb.profiler(cell.label) if self.profile else None,
+                coverage=cov,
+                counters=(self._cell_counters(
+                    fb, cell, cell.label if plan is not None else None)
+                    if err is None else None),
+            )
 
     @staticmethod
     def _cell_counters(target: Any, cell: SweepCell,
@@ -545,47 +589,51 @@ class CoVerifySession:
         plan = (cell.fault_plan.fork(cell.timing_label)
                 if cell.fault_plan is not None else None)
         cov = CoverageModel() if self.coverage is not None else None
-        t0 = time.perf_counter()
         err: Optional[str] = None
         slo = None
-        target = self._serving_factory(cell.backend, cell.devices, plan)
-        try:
-            run_open_loop(target, trace)
-            slo = SLOReport.from_run(trace, target, label=cell.label)
-        except Exception as e:            # cell failure must not kill sweep
-            err = f"{type(e).__name__}: {e}"
-        dt = time.perf_counter() - t0
-        violations = (list(target.violations)
-                      if hasattr(target, "violations")
-                      else list(target.mem.log.violations))
-        if cov is not None:
-            for log in target_logs(target):
-                for tx in log.txs:
-                    cov.hit_burst(tx.nbytes)
-                    cov.hit_congestion(tx.stall)
-            self._feed_arrival_coverage(cov, trace, target, violations)
-        # the equivalence payload: every completed request's token stream,
-        # compared exactly across backends and device counts
-        outputs = {f"tokens[{rid}]": np.asarray(req.out_tokens, np.int64)
-                   for rid, req in sorted(target.requests.items())
-                   if req.done}
-        return CellResult(
-            cell=cell,
-            outputs=outputs,
-            seconds=dt,
-            bridge_time=float(target.clock),
-            congestion=target.congestion_stats(),
-            violations=violations,
-            error=err,
-            faults=list(plan.events) if plan is not None else [],
-            profile=target.profiler(cell.label) if self.profile else None,
-            coverage=cov,
-            slo=slo,
-            counters=(self._cell_counters(
-                target, cell,
-                cell.timing_label if plan is not None else None)
-                if err is None else None),
-        )
+        # the open-loop run stands in for this cell's firmware
+        with span("fb.firmware") as fw:
+            target = self._serving_factory(cell.backend, cell.devices, plan)
+            try:
+                run_open_loop(target, trace)
+                slo = SLOReport.from_run(trace, target, label=cell.label)
+            except Exception as e:        # cell failure must not kill sweep
+                err = f"{type(e).__name__}: {e}"
+        with span("fb.cell.collect") as col:
+            violations = (list(target.violations)
+                          if hasattr(target, "violations")
+                          else list(target.mem.log.violations))
+            if cov is not None:
+                for log in target_logs(target):
+                    for tx in log.txs:
+                        cov.hit_burst(tx.nbytes)
+                        cov.hit_congestion(tx.stall)
+                self._feed_arrival_coverage(cov, trace, target, violations)
+            # the equivalence payload: every completed request's token
+            # stream, compared exactly across backends and device counts
+            outputs = {f"tokens[{rid}]": np.asarray(req.out_tokens,
+                                                    np.int64)
+                       for rid, req in sorted(target.requests.items())
+                       if req.done}
+            col.set(bytes=_nbytes(outputs))
+            return CellResult(
+                cell=cell,
+                outputs=outputs,
+                seconds=fw.seconds,
+                bridge_time=float(target.clock),
+                congestion=target.congestion_stats(),
+                violations=violations,
+                error=err,
+                faults=list(plan.events) if plan is not None else [],
+                profile=(target.profiler(cell.label) if self.profile
+                         else None),
+                coverage=cov,
+                slo=slo,
+                counters=(self._cell_counters(
+                    target, cell,
+                    cell.timing_label if plan is not None else None)
+                    if err is None else None),
+            )
 
     @staticmethod
     def _feed_arrival_coverage(cov: CoverageModel, trace: Any, target: Any,
@@ -614,35 +662,32 @@ class CoVerifySession:
                             profile=self.profile, topology=cell.topology,
                             coverage=cov)
         fab.register_op(cell.op, **self._ops[cell.op])
-        fw = self.fabric_firmware or self.firmware
-        t0 = time.perf_counter()
-        err: Optional[str] = None
-        try:
-            fw(fab, cell.op, cell.backend, **cell.config)
-        except Exception as e:            # cell failure must not kill sweep
-            err = f"{type(e).__name__}: {e}"
-        dt = time.perf_counter() - t0
-        if cov is not None:
-            for ev in fab.fault_events():
-                if ev.layer == "bridge":
-                    cov.hit("fault_kind", ev.kind)
-        return CellResult(
-            cell=cell,
-            outputs=fab.outputs(),
-            seconds=dt,
-            bridge_time=max([fab.time]
-                            + [d.mem.time for d in fab.devices]),
-            congestion=fab.device_congestion(),
-            violations=fab.violations,
-            error=err,
-            faults=fab.fault_events(),
-            links=fab.link_stats(),
-            profile=fab.profiler(cell.label) if self.profile else None,
-            coverage=cov,
-            counters=(self._cell_counters(
-                fab, cell, cell.label if plan is not None else None)
-                if err is None else None),
-        )
+        dt, err = self._run_firmware(self.fabric_firmware or self.firmware,
+                                     fab, cell)
+        with span("fb.cell.collect") as col:
+            if cov is not None:
+                for ev in fab.fault_events():
+                    if ev.layer == "bridge":
+                        cov.hit("fault_kind", ev.kind)
+            outputs = fab.outputs()
+            col.set(bytes=_nbytes(outputs))
+            return CellResult(
+                cell=cell,
+                outputs=outputs,
+                seconds=dt,
+                bridge_time=max([fab.time]
+                                + [d.mem.time for d in fab.devices]),
+                congestion=fab.device_congestion(),
+                violations=fab.violations,
+                error=err,
+                faults=fab.fault_events(),
+                links=fab.link_stats(),
+                profile=fab.profiler(cell.label) if self.profile else None,
+                coverage=cov,
+                counters=(self._cell_counters(
+                    fab, cell, cell.label if plan is not None else None)
+                    if err is None else None),
+            )
 
     def run(self, max_workers: Optional[int] = None,
             tol: float = 1e-3, bisect_failures: bool = True) -> SweepReport:
@@ -659,18 +704,34 @@ class CoVerifySession:
         first divergent transaction and the device state around it, at
         the cost of re-running only the two divergent cells — the
         debug-iteration path that used to require a manual full re-run.
+
+        The run is one ``fb.sweep`` span (``core/spans.py``) holding
+        ``fb.sweep.cells``, ``fb.sweep.precheck``, one
+        ``fb.sweep.compare`` per group and one ``fb.sweep.bisect`` per
+        localized group; their durations are the report's
+        ``phase_seconds``.
         """
-        t0 = time.perf_counter()
-        if max_workers == 1 or len(self.cells) <= 1:
-            results = [self._run_cell(c) for c in self.cells]
-        else:
-            # ex.map preserves submission order, so `results` is in cell
-            # order regardless of which thread finishes first — report
-            # rows, equivalence groups, divergence attachments, and the
-            # coverage merge below are completion-order independent
-            with ThreadPoolExecutor(max_workers=max_workers) as ex:
-                results = list(ex.map(self._run_cell, self.cells))
-        wall = time.perf_counter() - t0
+        self._sweeps += 1
+        with span("fb.sweep", sweep=self._sweeps, cells=len(self.cells)):
+            return self._run(self._sweeps, max_workers, tol,
+                             bisect_failures)
+
+    def _run(self, sweep: int, max_workers: Optional[int], tol: float,
+             bisect_failures: bool) -> SweepReport:
+        phase = dict.fromkeys(PHASES, 0.0)
+        run_cell = functools.partial(self._run_cell, sweep=sweep)
+        with span("fb.sweep.cells") as s:
+            if max_workers == 1 or len(self.cells) <= 1:
+                results = [run_cell(c) for c in self.cells]
+            else:
+                # ex.map preserves submission order, so `results` is in
+                # cell order regardless of which thread finishes first —
+                # report rows, equivalence groups, divergence attachments,
+                # and the coverage merge below are completion-order
+                # independent
+                with ThreadPoolExecutor(max_workers=max_workers) as ex:
+                    results = list(ex.map(run_cell, self.cells))
+        phase["cells"] = s.seconds
         if self.coverage is not None:
             # deterministic join: merge each cell's private model into the
             # session sink in cell order (never concurrently)
@@ -698,40 +759,48 @@ class CoVerifySession:
         # below, so a divergent group is flagged — and handed to the
         # bisection lane — before the expensive comparison even runs
         divergences: Dict[str, Any] = {}
-        counter_mismatches: Dict[str, Any] = {}
-        for key, rs in res_groups.items():
-            mismatch = self._counter_precheck(rs)
-            if mismatch is None:
-                continue
-            counter_mismatches[labels[key]] = mismatch
-            if bisect_failures:
-                a, b = mismatch["pair"]
+
+        def localize(key, pair) -> None:
+            with span("fb.sweep.bisect", group=labels[key]) as s:
                 try:
                     divergences[labels[key]] = self._bisect_cells(
-                        members[key][a], members[key][b])
+                        members[key][pair[0]], members[key][pair[1]])
                 except Exception as e:   # localization is best-effort —
                     divergences[labels[key]] = (   # never fail the sweep
                         f"bisect unavailable: {type(e).__name__}: {e}")
-        eq = {labels[k]: compare_outputs(outs, tol=tol)
-              for k, outs in groups.items() if len(outs) > 1}
+            phase["bisect"] += s.seconds
+
+        with span("fb.sweep.precheck", groups=len(res_groups)) as s:
+            prechecks = {key: self._counter_precheck(rs)
+                         for key, rs in res_groups.items()}
+        phase["precheck"] = s.seconds
+        counter_mismatches = {labels[key]: m for key, m in prechecks.items()
+                              if m is not None}
         if bisect_failures:
-            for key, outs in groups.items():
+            for key, m in prechecks.items():
+                if m is not None:
+                    localize(key, m["pair"])
+        eq: Dict[str, EquivalenceReport] = {}
+        for key, outs in groups.items():
+            if len(outs) < 2:
+                continue
+            with span("fb.sweep.compare", group=labels[key],
+                      elems=_compared_elems(outs)) as s:
+                eq[labels[key]] = compare_outputs(outs, tol=tol)
+            phase["compare"] += s.seconds
+        if bisect_failures:
+            for key in groups:
                 rep = eq.get(labels[key])
                 if rep is None or rep.passed or not rep.divergences:
                     continue
                 if labels[key] in divergences:
                     continue            # already localized by the oracle
-                pair = rep.divergences[0].pair
-                cells = members[key]
-                try:
-                    divergences[labels[key]] = self._bisect_cells(
-                        cells[pair[0]], cells[pair[1]])
-                except Exception as e:   # localization is best-effort —
-                    divergences[labels[key]] = (   # never fail the sweep
-                        f"bisect unavailable: {type(e).__name__}: {e}")
-        return SweepReport(cells=results, equivalence=eq, wall_seconds=wall,
+                localize(key, rep.divergences[0].pair)
+        return SweepReport(cells=results, equivalence=eq,
+                           wall_seconds=phase["cells"],
                            divergences=divergences, coverage=self.coverage,
-                           counter_mismatches=counter_mismatches)
+                           counter_mismatches=counter_mismatches,
+                           phase_seconds=phase)
 
     @staticmethod
     def _counter_precheck(rs: Dict[str, "CellResult"]

@@ -138,6 +138,7 @@ def flash_fwd(q, k, v, *, causal: bool, window: int = 0, bq: int = 512,
             pltpu.VMEM((bq, D), jnp.float32),   # output accumulator
         ],
         interpret=interpret,
+        name="flash_attention_fwd",
     )(q, k, v)
     return out, lse
 

@@ -66,24 +66,28 @@ def flash_chip_backends(bq: int, bk: int, causal: bool = True) -> dict:
     """``flash_backends(bq, bk, causal)`` for a TPU.
 
     compiled = the Pallas forward kernel compiled for the chip
-    (``interpret=False``), jitted once; its ``kernel`` attribute is that
-    jitted function, so a caller can read the lowered program.  The oracle
-    runs at float32 matmul precision: a TPU's default precision for an f32
-    dot is one bf16 pass.  Needs a TPU.
+    (``interpret=False``), jitted once under its own name, so the device
+    trace and the lowered program name it ``flash_attention_fwd``; the
+    ``kernel`` attribute is that jitted function, so a caller can read the
+    lowered program.  The oracle runs at float32 matmul precision: a TPU's
+    default precision for an f32 dot is one bf16 pass.  Needs a TPU.
     """
     table = flash_backends(bq, bk, causal)
     ref = table["oracle"]
-    kernel = jax.jit(lambda q, k, v: K.flash_fwd(
-        q, k, v, causal=causal, window=0, bq=bq, bk=bk, interpret=False)[0])
+
+    @jax.jit
+    def flash_attention_fwd(q, k, v):
+        return K.flash_fwd(q, k, v, causal=causal, window=0, bq=bq, bk=bk,
+                           interpret=False)[0]
 
     def oracle(q, k, v):
         with jax.default_matmul_precision("float32"):
             return ref(q, k, v)
 
     def compiled(q, k, v):
-        return np.asarray(kernel(jnp.asarray(q), jnp.asarray(k),
-                                 jnp.asarray(v)))
-    compiled.kernel = kernel
+        return np.asarray(flash_attention_fwd(jnp.asarray(q), jnp.asarray(k),
+                                              jnp.asarray(v)))
+    compiled.kernel = flash_attention_fwd
     return dict(table, oracle=oracle, compiled=compiled)
 
 

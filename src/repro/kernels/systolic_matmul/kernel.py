@@ -63,4 +63,5 @@ def matmul(a, b, *, bm: int = 128, bn: int = 128, bk: int = 128,
         out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
+        name="systolic_matmul",
     )(a, b)

@@ -87,21 +87,24 @@ def matmul_chip_backends(tile: int) -> dict:
     """``matmul_backends(tile)`` for a TPU.
 
     compiled = the Pallas kernel compiled for the chip (``interpret=False``),
-    jitted once; its ``kernel`` attribute is that jitted function, so a
-    caller can read the lowered program.  The oracle runs at float32
-    matmul precision: a TPU's default precision for an f32 dot is one bf16
-    pass.  Needs a TPU and (8, 128)-aligned tiles.
+    jitted once under its own name, so the device trace and the lowered
+    program name it ``systolic_matmul``; the ``kernel`` attribute is that
+    jitted function, so a caller can read the lowered program.  The
+    oracle runs at float32 matmul precision: a TPU's default precision for
+    an f32 dot is one bf16 pass.  Needs a TPU and (8, 128)-aligned tiles.
     """
     table = matmul_backends(tile)
     ref = table["oracle"]
-    kernel = jax.jit(lambda x, y: mm_kernel(
-        x, y, bm=tile, bn=tile, bk=tile, interpret=False))
+
+    @jax.jit
+    def systolic_matmul(x, y):
+        return mm_kernel(x, y, bm=tile, bn=tile, bk=tile, interpret=False)
 
     def oracle(x, y):
         with jax.default_matmul_precision("float32"):
             return ref(x, y)
 
     def compiled(x, y):
-        return np.asarray(kernel(jnp.asarray(x), jnp.asarray(y)))
-    compiled.kernel = kernel
+        return np.asarray(systolic_matmul(jnp.asarray(x), jnp.asarray(y)))
+    compiled.kernel = systolic_matmul
     return dict(table, oracle=oracle, compiled=compiled)

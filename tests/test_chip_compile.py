@@ -75,13 +75,18 @@ def test_flash_compiles_for_v5e(one_chip, kernel, dtype):
 def test_coverify_compiled_tier_compiles_for_v5e(one_chip, kind, block,
                                                  config):
     """Each chip_smoke co-verify cell's compiled tier, taken from the
-    backend table the smoke registers, is a Pallas kernel for the chip."""
+    backend table the smoke registers, is a Pallas kernel for the chip,
+    and the program and its kernel carry the kernel's name (the device
+    trace names the op by it)."""
     table = (matmul_chip_backends(block) if kind == "matmul"
              else flash_chip_backends(block, block))
     args = [jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
             for a in chip_smoke._kernel_inputs(kind, config)]
     text = table["compiled"].kernel.lower(*args).compile().as_text()
     assert "tpu_custom_call" in text
+    name = "systolic_matmul" if kind == "matmul" else "flash_attention_fwd"
+    assert f"%{name}." in text                         # the device op
+    assert f"jit({name})/{name}/pallas_call" in text   # jit and pallas_call
 
 
 SMOKE_SERVE = dict(n_requests=3, max_len=128, prompt_lens=(16, 48),

@@ -62,7 +62,8 @@ def test_repo_root_has_no_stray_artifacts():
     """Only the committed benchmark baselines may sit as .json at the
     repo root (the historical offender was profile_cnn.trace.json)."""
     committed = {"BENCH_runfarm.json", "BENCH_serving.json",
-                 "BENCH_simspeed.json", "BENCH_counters.json"}
+                 "BENCH_simspeed.json", "BENCH_counters.json",
+                 "BENCHMARK.json"}
     stray = sorted(p.name for p in ROOT.glob("*.json")
                    if p.name not in committed)
     assert not stray, f"untracked artifacts at repo root: {stray}"
