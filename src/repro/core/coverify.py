@@ -76,8 +76,9 @@ def coverify(firmware: Callable[[FireBridge, str], None],
             violations.extend(f"[{be}] {v}" for v in fb.log.violations)
             last_bridge = fb
 
-        with span("fb.sweep.compare", group="coverify"):
+        with span("fb.sweep.compare", group="coverify") as s:
             eq = compare_outputs(final_state, tol=tol)
+            s.set(same_elems=eq.same_elems)
 
     cong = None
     if congestion is not None and last_bridge is not None:

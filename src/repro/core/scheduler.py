@@ -786,7 +786,8 @@ class CoVerifySession:
                 continue
             with span("fb.sweep.compare", group=labels[key],
                       elems=_compared_elems(outs)) as s:
-                eq[labels[key]] = compare_outputs(outs, tol=tol)
+                eq[labels[key]] = rep = compare_outputs(outs, tol=tol)
+                s.set(same_elems=rep.same_elems)
             phase["compare"] += s.seconds
         if bisect_failures:
             for key in groups:

@@ -3,6 +3,8 @@ the session's report takes its times from its spans
 (benchmarks/chip/tests/test_bench_spans.py reads them from a trace)."""
 import time
 
+import jax
+import numpy as np
 import pytest
 
 from repro.core import CoVerifySession
@@ -55,3 +57,33 @@ def test_a_failing_sweep_times_its_bisection():
     rep = sess.run()
     assert not rep.passed and rep.divergences
     assert rep.phase_seconds["bisect"] > 0.0
+
+
+def test_compare_span_counts_what_byte_equality_settled(tmp_path):
+    """Both backends host-wrote ``a`` and ``b`` from one source, so the diff
+    settles them by byte equality; ``c`` differs by an ulp and is diffed."""
+    table = matmul_backends(jit=False)
+
+    def nudged(a, b):
+        c = table["oracle"](a, b)
+        return np.nextafter(c, np.inf, dtype=c.dtype)
+
+    sess = CoVerifySession(matmul_firmware)
+    sess.register_op("mm", oracle=table["oracle"], compiled=nudged)
+    sess.add_sweep("mm", ("oracle", "compiled"), [{"size": 32}])
+    jax.profiler.start_trace(str(tmp_path))
+    rep = sess.run(max_workers=2)
+    jax.profiler.stop_trace()
+    assert rep.passed
+    assert rep.phase_seconds["compare"] > 0.0
+    (eq,) = rep.equivalence.values()
+    inputs = 2 * 32 * 32
+    assert eq.same_elems == inputs
+    (path,) = tmp_path.glob("**/*.xplane.pb")
+    compares = [dict(ev.stats)
+                for plane in jax.profiler.ProfileData.from_file(
+                    str(path)).planes if plane.name.startswith("/host:")
+                for line in plane.lines for ev in line.events
+                if ev.name == "fb.sweep.compare"]
+    assert [(s["elems"], s["same_elems"]) for s in compares] == \
+        [(3 * 32 * 32, inputs)]
