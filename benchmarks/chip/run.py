@@ -8,7 +8,10 @@ configuration file (``configs/<config>.json``, with its plain reference
 beside it), its traffic file (``traffic/<traffic>.json``, whose ``driver``
 names the loop in ``drivers/``), and each per-layer metric's reader
 (``metrics/<metric>.py``).  A new cell, configuration, mix or metric is new
-files and entries; this file does not change.
+files and entries; this file does not change.  A new cell whose driver
+reports an end-to-end metric that lists its cells (``sweep_s``) appends its
+name to that metric's ``workloads`` list; the metric's name, unit and bound
+stay.  Its driver's CPU test case is ``tests/cases/<driver>.py``.
 
 With ``--trace 0`` the result line carries the cell's end-to-end metrics,
 with ``--trace 1`` its per-layer metrics, read from a profiler trace of
@@ -148,6 +151,13 @@ def run_cell(found: Dict[str, Any], ctx: Ctx, peak: dict) -> Dict[str, Any]:
         metrics = {"setup_s": {"value": ctx.setup_s, "unit": "s"}}
         units = {m["name"]: m["unit"] for m in found["end_to_end"]}
         for name, v in out["e2e"].items():
+            if name not in units:
+                raise RuntimeError(
+                    f"driver {ctx.traffic['driver']!r} reports the "
+                    f"end-to-end metric {name!r}, which BENCHMARK.json does "
+                    f"not apply to cell {found['cell']['name']!r}: append "
+                    f"{found['cell']['name']!r} to the \"workloads\" list "
+                    f"of {name!r} in BENCHMARK.json's end_to_end")
             metrics[name] = {"value": v, "unit": units[name]}
         result["metrics"] = metrics
         result["device"] = ctx.device

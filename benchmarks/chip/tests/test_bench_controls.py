@@ -1,8 +1,8 @@
 """The control of ``correct``, at a size a test run holds: the plain
-reference put in the compiled kernel's place and computed in float8 (the
-next type below the configuration's bfloat16) goes through the same
-session, DDR writeback and comparison as the program, and the run comes
-out not correct."""
+reference put in the compiled kernel's place and computed in the next type
+below the configuration's (the case's ``CONTROL``: float8 below bfloat16)
+goes through the same session, DDR writeback and comparison as the
+program, and the run comes out not correct."""
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -16,11 +16,13 @@ F8 = jnp.float8_e4m3fn
 @pytest.mark.parametrize("seed", [11, 12, 13])
 @pytest.mark.parametrize("workload", cells.WORKLOADS)
 def test_control_is_not_correct(workload, seed):
-    result, _ = cells.run_cell(cells.found(workload), seed=seed, control=F8)
+    case = cells.case(workload)
+    result, _ = cells.run_cell(cells.found(workload), seed=seed,
+                               control=case.CONTROL)
     assert not result["correct"], result["checks"]
     checks = result["checks"]
-    assert checks["matmul_err"]["value"] > checks["matmul_err"]["limit"]
-    assert checks["flash_err"]["value"] > checks["flash_err"]["limit"]
+    for name in case.CONTROL_FAILS:
+        assert checks[name]["value"] > checks[name]["limit"], name
 
 
 def test_kernel_control_reads_far_above_bf16_rounding():

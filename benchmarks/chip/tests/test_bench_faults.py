@@ -2,7 +2,8 @@
 correct: the chip check is skipped, the rest of the run is driven as on
 the chip, and the compiled tier's kernel answers wrongly in one of the
 ways a co-verified kernel can (one chip: there is no exchange between
-chips to leave out)."""
+chips to leave out).  Each cell's case (``tests/cases/<driver>.py``) names
+the ops broken and the checks each fault must fail."""
 import numpy as np
 import pytest
 
@@ -15,8 +16,8 @@ def _unchanged(out):
 
 
 def _half_left_out(out):
-    """Only the first half of the rows (matmul) or heads (flash) is
-    computed."""
+    """Only the first half of the rows (a 2-D answer, as the matmul's) or
+    of the heads (axis 1, as flash's) is computed."""
     out = np.array(out)
     axis = 0 if out.ndim == 2 else 1
     idx = [slice(None)] * out.ndim
@@ -38,9 +39,9 @@ FAULTS = {"unchanged": _unchanged, "half_left_out": _half_left_out,
           "altered": _altered}
 
 
-def _broken_tables(fault, ops=("matmul", "flash")):
-    def tables(tile):
-        t = cells.cpu_tables(tile)
+def _broken_tables(case, fault, ops):
+    def tables(*args):
+        t = case.cpu_tables(*args)
         for op in ops:
             table = t[op]
             good = table["compiled"]
@@ -50,35 +51,33 @@ def _broken_tables(fault, ops=("matmul", "flash")):
 
 
 def _failing(checks):
-    return sorted(n for n, c in checks.items() if c["value"] > c["limit"])
+    return {n for n, c in checks.items() if c["value"] > c["limit"]}
+
+
+def _check_broken(workload, fault, ops=None):
+    case = cells.case(workload)
+    ops = case.OPS if ops is None else ops
+    result, _ = cells.run_cell(cells.found(workload),
+                               tables=_broken_tables(case, FAULTS[fault],
+                                                     ops))
+    assert not result["correct"]
+    must_fail, must_pass = case.fault_checks(ops, fault)
+    failing = _failing(result["checks"])
+    assert must_fail <= failing, (must_fail, result["checks"])
+    assert not must_pass & failing, (must_pass, result["checks"])
 
 
 @pytest.mark.parametrize("fault", sorted(FAULTS))
 @pytest.mark.parametrize("workload", cells.WORKLOADS)
 def test_broken_kernel_answer_is_not_correct(workload, fault):
-    result, _ = cells.run_cell(cells.found(workload),
-                               tables=_broken_tables(FAULTS[fault]))
-    assert not result["correct"]
-    checks = result["checks"]
-    assert checks["matmul_err"]["value"] > checks["matmul_err"]["limit"]
-    assert "sweeps_failed" in _failing(checks)
+    """Every op of the cell answers wrongly."""
+    _check_broken(workload, fault)
 
 
 @pytest.mark.parametrize("fault", sorted(FAULTS))
-@pytest.mark.parametrize("workload", cells.WORKLOADS)
+@pytest.mark.parametrize("workload", cells.cells_breaking("flash"))
 def test_broken_flash_alone_is_not_correct(workload, fault):
-    """Only the attention kernel answers wrongly.  A flash output left as
-    allocated, or with half its heads left out, reads under 1 by
-    ``flash_err`` (each element's error is at most its own |reference|),
-    under that number's limit; the session's diff against the oracle
-    catches it (``sweeps_failed``).  One element moved by 4 rms fails
-    both."""
-    result, _ = cells.run_cell(cells.found(workload),
-                               tables=_broken_tables(FAULTS[fault],
-                                                     ops=("flash",)))
-    assert not result["correct"]
-    failing = _failing(result["checks"])
-    assert "matmul_err" not in failing
-    assert "sweeps_failed" in failing
-    if fault == "altered":
-        assert "flash_err" in failing
+    """Only the attention kernel answers wrongly; the case says which
+    checks that fails (the session's diff, and ``flash_err`` for an altered
+    element) and which it leaves passing (the matmul's)."""
+    _check_broken(workload, fault, ops=("flash",))
