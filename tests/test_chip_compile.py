@@ -18,6 +18,7 @@ import chip_smoke
 from repro.configs import get_config, smoke
 from repro.kernels.flash_attention import kernel as FK
 from repro.kernels.flash_attention.sweep import flash_chip_backends
+from repro.kernels.mamba2_scan.sweep import ssd_chip_backends
 from repro.kernels.systolic_matmul.kernel import matmul
 from repro.kernels.systolic_matmul.sweep import matmul_chip_backends
 from repro.models import init_params
@@ -87,6 +88,25 @@ def test_coverify_compiled_tier_compiles_for_v5e(one_chip, kind, block,
     name = "systolic_matmul" if kind == "matmul" else "flash_attention_fwd"
     assert f"%{name}." in text                         # the device op
     assert f"jit({name})/{name}/pallas_call" in text   # jit and pallas_call
+
+
+def test_ssd_compiles_for_v5e(one_chip):
+    """Nemotron-H-47B's Mamba-2 SSD scan at its published widths over its
+    whole 8192-token context (B 1, H 256, P 64, G 8, N 256, chunk 128,
+    head block 8, bf16 x/B/C), through the chip backend table's compiled
+    tier, which carries the kernel's name."""
+    B, L, H, P, G, N = 1, 8192, 256, 64, 8, 256
+
+    def arg(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    bc = arg((B, G, L, N), jnp.bfloat16)
+    args = [arg((B, H, L, P), jnp.bfloat16), arg((B, H, L), jnp.float32),
+            bc, bc, arg((H,), jnp.float32), arg((H,), jnp.float32)]
+    kernel = ssd_chip_backends(128, 8)["compiled"].kernel
+    text = kernel.lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+    assert "%ssd_scan." in text
+    assert "jit(ssd_scan)/ssd_scan/pallas_call" in text
 
 
 SMOKE_SERVE = dict(n_requests=3, max_len=128, prompt_lens=(16, 48),

@@ -237,9 +237,37 @@ def test_flash_burst_list_per_tile():
 def test_ssd_burst_list_per_tile():
     txs = ssd_ops.transactions(2, 256, 16, 32, 64, chunk=128, hb=8)
     _check_bursts(txs, 4)
-    # state writes once per (batch, head-group), not per chunk
+    # state writes once per (batch, head block), not per chunk
     n_state = sum(1 for e, _, _, _ in txs if e == "dma_state")
     assert n_state == 2 * (16 // 8)
+    # grouped and head-major (bf16 x/B/C/y, f32 dt/state): a head block
+    # reads only its own group's B/C chunks, and x, dt and y move once
+    B, L, H, P, N, G, hb, cl = 2, 256, 16, 32, 64, 2, 4, 128
+    txs = ssd_ops.transactions(B, L, H, P, N, G=G, chunk=cl, hb=hb,
+                               dtype_bytes=2)
+    _check_bursts(txs, 4)
+    for engine, nbytes in (("dma_x", B * H * L * P * 2),
+                           ("dma_y", B * H * L * P * 2),
+                           ("dma_dt", B * H * L * 4),
+                           ("dma_state", B * H * P * N * 4)):
+        assert sum(n for e, _, _, n in txs if e == engine) == nbytes
+    bc = L * N * 2                          # one (batch, group)'s B or C
+    b_base = B * H * L * P * 2 + B * H * L * 4
+    blocks, cur = [], []
+    for t in txs:
+        cur.append(t)
+        if t[0] == "dma_state":
+            blocks.append(cur)
+            cur = []
+    assert len(blocks) == B * H // hb and not cur
+    for i, blk in enumerate(blocks):
+        b, h0 = divmod(i, H // hb)
+        g = h0 * hb // (H // G)
+        reads = sorted(a for e, _, a, _ in blk if e == "dma_bc")
+        want = [base + (b * G + g) * bc + c * cl * N * 2
+                for base in (b_base, b_base + B * G * bc)
+                for c in range(L // cl)]
+        assert reads == want
 
 
 def test_wkv_burst_list_per_tile():
