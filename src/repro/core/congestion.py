@@ -33,6 +33,7 @@ from __future__ import annotations
 import copy
 import dataclasses
 from collections import defaultdict
+from collections.abc import Sequence
 from typing import Dict, List, Optional
 
 import numpy as np
@@ -90,14 +91,60 @@ class CongestionConfig:
         )
 
 
+class TimelineSnapshot(Sequence):
+    """An arbitration-order timeline as it stood when it was taken, kept
+    as the segments it was logged in: lists of Transaction objects and
+    ``BurstBatch`` segments.  Batches become Transaction objects on the
+    first read of an element, through their cached ``materialize()``, so
+    the objects are the ones the log and the link hold; ``len`` reads
+    no element."""
+
+    __slots__ = ("_segments", "_n", "_txs")
+
+    def __init__(self, segments) -> None:
+        self._segments = tuple(segments)
+        self._n = sum(len(seg) for seg in self._segments)
+        self._txs: Optional[List[Transaction]] = None
+
+    def _list(self) -> List[Transaction]:
+        if self._txs is None:
+            txs: List[Transaction] = []
+            for seg in self._segments:
+                txs.extend(seg.materialize() if isinstance(seg, BurstBatch)
+                           else seg)
+            self._txs = txs
+        return self._txs
+
+    def __len__(self) -> int:
+        return self._n
+
+    def __getitem__(self, i):
+        return self._list()[i]
+
+    def __iter__(self):
+        return iter(self._list())
+
+    def __eq__(self, other) -> bool:
+        if isinstance(other, TimelineSnapshot):
+            other = other._list()
+        return self._list() == other
+
+    __hash__ = None
+
+    def __repr__(self) -> str:
+        return repr(self._list())
+
+
 @dataclasses.dataclass
 class CongestionResult:
-    """Per-run link statistics — the Fig. 8 stall/utilization series."""
+    """Per-run link statistics — the Fig. 8 stall/utilization series.
+    ``timeline`` is a list, or a ``TimelineSnapshot`` from
+    ``LinkModel.result()``."""
     makespan: float
     per_engine_stall: Dict[str, float]
     per_engine_busy: Dict[str, float]
     link_utilization: float
-    timeline: List[Transaction]
+    timeline: Sequence
 
     def summary(self) -> dict:
         return {
@@ -157,8 +204,9 @@ class LinkModel:
     @property
     def timeline(self) -> List[Transaction]:
         """Arbitration-order transaction timeline.  Batch-submitted
-        segments materialize on first read (profiler/result paths); the
-        hot path appends lazily."""
+        segments materialize on first read (profiler, checkpoints); the
+        hot path appends lazily, and ``result()`` snapshots the segments
+        without reading them."""
         if self._tl_pending:
             for b in self._tl_pending:
                 self._timeline.extend(b.materialize())
@@ -509,8 +557,14 @@ class LinkModel:
         self._timeline[:] = state["timeline"]
 
     def result(self) -> CongestionResult:
-        """Snapshot the Fig. 8 statistics accumulated so far."""
-        makespan = max((t.complete for t in self.timeline), default=0.0)
+        """Snapshot the Fig. 8 statistics accumulated so far.  Pending
+        batch segments stay columns: the makespan is the max of their
+        ``complete`` column and the materialized entries' (the same
+        values, so the same float), and the timeline is a snapshot that
+        materializes on first read."""
+        makespan = max([t.complete for t in self._timeline]
+                       + [b.rec["complete"].max().item()
+                          for b in self._tl_pending], default=0.0)
         util = ((self._total_bytes / self.cfg.link_bytes_per_cycle)
                 / makespan if makespan else 0.0)
         return CongestionResult(
@@ -518,7 +572,8 @@ class LinkModel:
             per_engine_stall=dict(self._stall),
             per_engine_busy=dict(self._busy),
             link_utilization=util,
-            timeline=list(self.timeline),
+            timeline=TimelineSnapshot([list(self._timeline),
+                                       *self._tl_pending]),
         )
 
 

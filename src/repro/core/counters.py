@@ -21,10 +21,11 @@ Design rules (each one is load-bearing for a regression tier):
   not on the interval, so a stream sampled at 2I is exactly the
   even-boundary subsequence of the stream sampled at I
   (tests/test_counters.py::test_sampling_interval_invariance).
-* **Same lazy-digest discipline as ``TransactionLog``.**  Canonical
-  lines and the running sha256 are cached append-only; ``set_state``
-  (the one non-append mutation) invalidates them and bumps an epoch so
-  a restored stream can never alias a stale memo.
+* **Same lazy-digest discipline as ``TransactionLog``.**  The running
+  sha256 covers an append-only prefix of the rows; ``set_state`` (the
+  one non-append mutation) resets it and bumps an epoch so a restored
+  stream can never alias a stale memo.  Rendering costs one format per
+  run of rows that share a values tuple (one ``tick``), not per row.
 * **Two digest scopes** mirror replay's state/functional fingerprint
   split.  ``digest()`` covers the full sampled stream and is invariant
   across backends at a fixed device count (modeled timing is
@@ -41,6 +42,8 @@ from __future__ import annotations
 import contextlib
 import dataclasses
 import hashlib
+import math
+import threading
 from typing import Any, Callable, Dict, Iterable, List, Optional, Tuple
 
 # Default sampling interval in modeled cycles.  A power of two so that
@@ -66,6 +69,32 @@ def sampling_disabled():
         yield
     finally:
         _ENABLED = prev
+
+
+class _RenderTally(threading.local):
+    """Rows rendered and values suffixes formatted by ``CounterStream`` on
+    one thread, for ``render_tally``."""
+    rows = 0
+    runs = 0
+
+
+_TALLY = _RenderTally()
+
+
+def render_tally() -> Tuple[int, int]:
+    """(rows, runs) that every ``CounterStream`` has rendered on the
+    calling thread so far; a span counts its own as the difference."""
+    return _TALLY.rows, _TALLY.runs
+
+
+def _renders_alike(a: Tuple, b: Tuple) -> bool:
+    """Whether two values tuples render to the same text: equal, of the
+    same types element by element, and with zeros of the same sign
+    (``0.0 == -0.0`` and ``1 == 1.0`` render differently)."""
+    return a == b and all(
+        type(x) is type(y) and (x != 0 or
+                                math.copysign(1.0, x) == math.copysign(1.0, y))
+        for x, y in zip(a, b))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -94,17 +123,18 @@ class CounterStream:
     """Append-only columnar sample stream with an incremental digest.
 
     Rows are (boundary_time, values...) tuples appended by the owning
-    bank's ``tick``.  Rendering and hashing follow ``TransactionLog``'s
-    lazy-digest discipline exactly: ``_lines``/``_hash`` cover a prefix
-    and extend append-only; ``set_state`` clears them and bumps
-    ``_epoch`` so the keyed digest memo can never serve a stale value.
+    bank's ``tick``; one tick appends the same values tuple to every row
+    it emits.  Hashing follows ``TransactionLog``'s lazy-digest
+    discipline: ``_hash`` covers the first ``_done`` rows and extends
+    append-only; ``set_state`` resets it and bumps ``_epoch`` so the
+    keyed digest memo can never serve a stale value.
     """
 
     def __init__(self, specs: Tuple[CounterSpec, ...]) -> None:
         self.specs = specs
         self.times: List[float] = []
         self.rows: List[Tuple] = []
-        self._lines: List[str] = []
+        self._done = 0
         self._hash = hashlib.sha256()
         self._digest_memo: Optional[Tuple[Tuple, str]] = None
         self._epoch = 0
@@ -123,20 +153,34 @@ class CounterStream:
             f"{v:.6f}" if s.is_float else str(v)
             for s, v in zip(self.specs, values))
 
-    def _render(self) -> None:
-        done = len(self._lines)
-        for t, row in zip(self.times[done:], self.rows[done:]):
-            line = f"{t:.6f} {self._fmt(row)}"
-            self._hash.update(line.encode())
-            self._hash.update(b"\n")
-            self._lines.append(line)
+    def _render(self, start: int) -> str:
+        """Canonical text of rows ``start:``, one newline-ended line per
+        row.  A run of rows that render alike (by identity first) shares
+        one formatted values suffix; each row adds only its time."""
+        times, rows = self.times, self.rows
+        n = len(rows)
+        parts: List[str] = []
+        runs = 0
+        i = start
+        while i < n:
+            row = rows[i]
+            j = i + 1
+            while j < n and (rows[j] is row or _renders_alike(rows[j], row)):
+                j += 1
+            tail = f" {self._fmt(row)}\n"
+            parts.append(tail.join([f"{t:.6f}" for t in times[i:j]]))
+            parts.append(tail)
+            runs += 1
+            i = j
+        _TALLY.rows += n - start
+        _TALLY.runs += runs
+        return "".join(parts)
 
     def canonical(self) -> List[str]:
         """Stable one-line-per-sample rendering (floats fixed to 6
         decimals, like ``TransactionLog.canonical_line``) — the golden
         counter-corpus format (tests/golden/*.counters)."""
-        self._render()
-        return list(self._lines)
+        return self._render(0).split("\n")[:-1]
 
     def digest(self) -> str:
         """sha256 over the canonical stream — the counter-diff oracle's
@@ -145,7 +189,8 @@ class CounterStream:
         key = (self._epoch, len(self.times))
         if self._digest_memo is not None and self._digest_memo[0] == key:
             return self._digest_memo[1]
-        self._render()
+        self._hash.update(self._render(self._done).encode())
+        self._done = len(self.times)
         out = self._hash.hexdigest()
         self._digest_memo = (key, out)
         return out
@@ -157,7 +202,7 @@ class CounterStream:
     def set_state(self, state: Dict[str, Any]) -> None:
         self.times[:] = state["times"]
         self.rows[:] = state["rows"]
-        self._lines = []
+        self._done = 0
         self._hash = hashlib.sha256()
         self._digest_memo = None
         self._epoch += 1

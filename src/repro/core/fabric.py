@@ -54,7 +54,7 @@ import numpy as np
 
 from repro.core.bridge import FireBridge, MemoryBridge
 from repro.core.congestion import (CongestionConfig, CongestionResult,
-                                   LinkModel)
+                                   LinkModel, TimelineSnapshot)
 from repro.core.counters import (CounterBank, CounterSpec,
                                  register_link_counters,
                                  register_switch_port_counters)
@@ -720,10 +720,10 @@ class FabricCluster:
                 for e, v in r.per_engine_busy.items()}
         makespan = max(r.makespan for _, r in per)
         util = sum(r.link_utilization for _, r in per) / len(per)
-        timeline = [t for _, r in per for t in r.timeline]
-        return CongestionResult(makespan=makespan, per_engine_stall=stall,
-                                per_engine_busy=busy, link_utilization=util,
-                                timeline=timeline)
+        return CongestionResult(
+            makespan=makespan, per_engine_stall=stall, per_engine_busy=busy,
+            link_utilization=util,
+            timeline=TimelineSnapshot([r.timeline for _, r in per]))
 
     @property
     def violations(self) -> List[str]:

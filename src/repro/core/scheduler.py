@@ -25,6 +25,7 @@ sequential per-op loop on a >=8-cell sweep (the Fig. 5 batched lane).
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 import hashlib
@@ -35,11 +36,13 @@ import numpy as np
 
 from repro.core.bridge import FireBridge
 from repro.core.congestion import CongestionConfig, CongestionResult
+from repro.core.counters import render_tally
 from repro.core.coverage import CoverageModel
 from repro.core.equivalence import EquivalenceReport, compare_outputs
 from repro.core.fabric import FabricCluster
 from repro.core.fuzz import FaultEvent, FaultPlan
 from repro.core.spans import span
+from repro.core.transactions import materialized_tally
 
 # the phases of CoVerifySession.run, as SweepReport.phase_seconds keys
 PHASES = ("cells", "precheck", "compare", "bisect")
@@ -85,6 +88,21 @@ def _config_key(config: Dict[str, Any]) -> Tuple:
 
 def _nbytes(outputs: Dict[str, np.ndarray]) -> int:
     return sum(a.nbytes for a in outputs.values())
+
+
+@contextlib.contextmanager
+def _collect_span():
+    """``fb.cell.collect`` around one finished cell's summaries, with the
+    Python work they did on this thread as arguments: counter rows
+    digested (``samples``), values suffixes formatted (``runs``) and
+    Transaction objects built (``materialized``)."""
+    rows0, runs0 = render_tally()
+    built0 = materialized_tally()
+    with span("fb.cell.collect") as col:
+        yield col
+        rows, runs = render_tally()
+        col.set(samples=rows - rows0, runs=runs - runs0,
+                materialized=materialized_tally() - built0)
 
 
 def _compared_elems(outs: Dict[str, Dict[str, np.ndarray]]) -> int:
@@ -519,7 +537,7 @@ class CoVerifySession:
                         profile=self.profile)
         fb.register_op(cell.op, **self._ops[cell.op])
         dt, err = self._run_firmware(self.firmware, fb, cell)
-        with span("fb.cell.collect") as col:
+        with _collect_span() as col:
             if cov is not None:
                 self._feed_coverage(cov, fb.log, plan)
             outputs = {n: b.array.copy() for n, b in fb.mem.buffers.items()}
@@ -599,7 +617,7 @@ class CoVerifySession:
                 slo = SLOReport.from_run(trace, target, label=cell.label)
             except Exception as e:        # cell failure must not kill sweep
                 err = f"{type(e).__name__}: {e}"
-        with span("fb.cell.collect") as col:
+        with _collect_span() as col:
             violations = (list(target.violations)
                           if hasattr(target, "violations")
                           else list(target.mem.log.violations))
@@ -664,7 +682,7 @@ class CoVerifySession:
         fab.register_op(cell.op, **self._ops[cell.op])
         dt, err = self._run_firmware(self.fabric_firmware or self.firmware,
                                      fab, cell)
-        with span("fb.cell.collect") as col:
+        with _collect_span() as col:
             if cov is not None:
                 for ev in fab.fault_events():
                     if ev.layer == "bridge":

@@ -18,6 +18,7 @@ from __future__ import annotations
 import contextlib
 import dataclasses
 import hashlib
+import threading
 from collections import defaultdict
 from typing import Callable, Dict, Iterable, List, Optional, Sequence, Tuple
 
@@ -40,6 +41,21 @@ class Transaction:
     # Never rendered into canonical lines — golden traces are unaffected.
     dos: float = 0.0
     fault_delay: float = 0.0
+
+
+class _BuildTally(threading.local):
+    """Transaction objects ``BurstBatch.materialize`` has built on one
+    thread, for ``materialized_tally``."""
+    n = 0
+
+
+_BUILT = _BuildTally()
+
+
+def materialized_tally() -> int:
+    """Transaction objects built from batch columns on the calling
+    thread so far; a span counts its own as the difference."""
+    return _BUILT.n
 
 
 # Column layout of one burst batch: every numeric Transaction field,
@@ -174,6 +190,7 @@ class BurstBatch:
                     r["complete"].tolist(), r["dos"].tolist(),
                     r["fault_delay"].tolist(), self.engine, self.kind,
                     self.tag)]
+            _BUILT.n += len(self._txs)
         return self._txs
 
     def canonical_lines(self) -> List[str]:

@@ -21,6 +21,8 @@ Four pillars, mirroring the module's design rules:
 numpy fallback for environments without hypothesis.
 """
 import functools
+import hashlib
+import pickle
 import sys
 from pathlib import Path
 
@@ -32,9 +34,10 @@ sys.path.insert(0, str(ROOT))
 sys.path.insert(0, str(ROOT / "src"))
 
 from repro.core import CongestionConfig, FireBridge
-from repro.core.counters import (counter_banks, diff_streams,
+from repro.core.counters import (CounterBank, CounterSpec, CounterStream,
+                                 counter_banks, diff_streams,
                                  functional_digest, functional_totals,
-                                 merged_digest, merged_totals,
+                                 merged_digest, merged_totals, render_tally,
                                  sampling_disabled)
 from repro.core.profiler import CATEGORIES
 from repro.core.scheduler import CoVerifySession
@@ -224,6 +227,151 @@ def test_counter_state_roundtrip():
     # the memo for the old epoch
     bank.set_state(bank.get_state())
     assert bank.digest() == d0
+
+
+# ------------------------------------------- grouped rendering vs per row
+def _reference_lines(stream) -> list:
+    """The per-row renderer the grouped one replaced: every row formats
+    its time and all of its values."""
+    def fmt(values):
+        return " ".join(f"{v:.6f}" if s.is_float else str(v)
+                        for s, v in zip(stream.specs, values))
+    return [f"{t:.6f} {fmt(row)}" for t, row in zip(stream.times, stream.rows)]
+
+
+def _reference_digest(stream) -> str:
+    h = hashlib.sha256()
+    for line in _reference_lines(stream):
+        h.update(line.encode())
+        h.update(b"\n")
+    return h.hexdigest()
+
+
+def _check_stream(stream) -> None:
+    assert stream.canonical() == _reference_lines(stream)
+    assert stream.digest() == _reference_digest(stream)
+
+
+def _probed_bank(interval=256.0):
+    """A bank of one int and one float probe over a mutable state, plus
+    an owned int counter."""
+    state = {"n": 0, "c": 0.0}
+    bank = CounterBank("t", interval)
+    bank.register(CounterSpec("n", "events"), lambda: state["n"])
+    bank.register(CounterSpec("c", "cycles"), lambda: state["c"])
+    bank.register(CounterSpec("owned", "events"))
+    return bank, state
+
+
+def _case_boundaries():
+    """Ticks that cross no boundary, one, and many, with values that
+    change between ticks and fractional floats; checked once at the end
+    and again after one more tick."""
+    bank, st = _probed_bank()
+    for now, n, c in ((100.0, 1, 1 / 3), (256.0, 2, 2.5), (300.0, 3, 3.25),
+                      (511.9, 4, 1e9 / 7), (512.0, 4, 1e9 / 7),
+                      (256.0 * 70 + 3, 5, 0.1), (256.0 * 70 + 4, 6, 0.2),
+                      (256.0 * 4000, 7, 12345.678901234)):
+        st["n"], st["c"] = n, c
+        bank.inc("owned", 2)
+        bank.tick(now)
+    _check_stream(bank.stream)
+    st["n"] += 1
+    bank.tick(256.0 * 4003)
+    return [bank.stream]
+
+
+def _case_digest_then_more():
+    """digest(), more ticks, digest() again: the running hash resumes
+    where the last digest stopped, also in the middle of equal values."""
+    bank, st = _probed_bank()
+    for k in range(1, 40):
+        if k % 3 == 0:
+            st["c"] += 0.75
+        st["n"] = k // 5
+        bank.tick(256.0 * k * k / 4)
+        if k % 7 == 0:
+            _check_stream(bank.stream)
+    return [bank.stream]
+
+
+def _case_interval_subsequence():
+    """A non-integer interval at I, 2I and 4I over one tick sequence:
+    each stream renders as the reference does, and the coarser streams
+    are the fine stream's every second and every fourth row."""
+    interval = 0.1 * 3
+    banks = []
+    for mult in (1, 2, 4):
+        bank, st = _probed_bank(interval * mult)
+        for now in np.cumsum(np.random.default_rng(5).uniform(0, 9, 60)):
+            st["n"] += 1
+            st["c"] = float(now) / 3
+            bank.tick(float(now))
+        banks.append(bank.stream)
+    fine, two, four = banks
+    assert fine.n_samples > two.n_samples > four.n_samples > 0
+    assert _reference_lines(two) == _reference_lines(fine)[1::2]
+    assert _reference_lines(four) == _reference_lines(fine)[3::4]
+    return banks
+
+
+def _case_set_state_then_more():
+    """set_state back to a checkpoint, then more ticks; and a fresh bank
+    restored from a pickled copy, whose rows are equal but not
+    identical."""
+    bank, st = _probed_bank()
+    for k in range(1, 9):
+        st["n"] = k
+        bank.tick(256.0 * 3 * k)
+    saved = bank.get_state()
+    _check_stream(bank.stream)
+    st["n"] = 100
+    bank.tick(256.0 * 40)
+    _check_stream(bank.stream)
+    bank.set_state(saved)
+    st["n"] = 200
+    bank.tick(256.0 * 30)
+    fresh, _ = _probed_bank()
+    fresh.set_state(pickle.loads(pickle.dumps(saved)))
+    assert fresh.stream.rows[0] is not saved["stream"]["rows"][0]
+    _check_stream(fresh.stream)
+    fresh.tick(256.0 * 30)
+    return [bank.stream, fresh.stream]
+
+
+def _case_equal_not_identical():
+    """Rows that are equal but not the same tuple, including equal values
+    that render differently: -0.0 against 0.0, and 1 against 1.0 and
+    True in an integer column."""
+    stream = CounterStream((CounterSpec("n", "events"),
+                            CounterSpec("c", "cycles")))
+    rows = [(1, 0.0), (1, 0.0), (1, -0.0), (1, -0.0), (1.0, 0.0),
+            (True, 0.0), (1, 0.0), (2, 1.5), (2, 1.5), (2, 1.5)]
+    for k, row in enumerate(rows, 1):
+        stream.append(256.0 * k, tuple(list(row)))
+    _check_stream(stream)
+    rows0, runs0 = render_tally()
+    stream.canonical()
+    rows1, runs1 = render_tally()
+    assert (rows1 - rows0, runs1 - runs0) == (10, 6)
+    return [stream]
+
+
+@pytest.mark.parametrize("case", [
+    _case_boundaries, _case_digest_then_more, _case_interval_subsequence,
+    _case_set_state_then_more, _case_equal_not_identical])
+def test_grouped_render_equals_per_row_reference(case):
+    """The grouped renderer (one values suffix per run of rows) gives
+    the per-row reference's lines and digest on every stream, and
+    formats one suffix per tick, not per row."""
+    for stream in case():
+        rows0, runs0 = render_tally()
+        lines = stream.canonical()
+        rows1, runs1 = render_tally()
+        assert lines == _reference_lines(stream)
+        assert stream.digest() == _reference_digest(stream)
+        assert rows1 - rows0 == stream.n_samples
+        assert runs1 - runs0 <= len({id(r) for r in stream.rows})
 
 
 # ------------------------------------------------ replay/monotone invariants

@@ -22,15 +22,18 @@ runs property-based; locally the 200 seeded random cases below cover
 the same space deterministically.
 """
 import copy
+import dataclasses
 import hashlib
+import struct
 
 import numpy as np
 import pytest
 
-from repro.core.congestion import CongestionConfig, LinkModel
+from repro.core.congestion import (CongestionConfig, CongestionResult,
+                                   LinkModel)
 from repro.core.fuzz import FaultPlan
 from repro.core.transactions import (BURST_DTYPE, BurstBatch, Transaction,
-                                     TransactionLog)
+                                     TransactionLog, materialized_tally)
 
 try:
     from hypothesis import given, settings, strategies as st
@@ -317,6 +320,135 @@ def test_batch_timeline_log_aliasing():
         lm.submit_batch(_batch(spec), log)
     assert len(lm.timeline) == len(log.txs)
     assert all(a is b for a, b in zip(lm.timeline, log.txs))
+
+
+# ------------------------------------------------------ lazy link result
+
+def _eager_result(lm):
+    """``LinkModel.result()`` as it was before its timeline became a
+    snapshot: materialize every batch, take the max over the objects and
+    copy the list."""
+    tl = lm.timeline
+    makespan = max((t.complete for t in tl), default=0.0)
+    util = ((lm._total_bytes / lm.cfg.link_bytes_per_cycle) / makespan
+            if makespan else 0.0)
+    return CongestionResult(makespan, dict(lm._stall), dict(lm._busy), util,
+                            list(tl))
+
+
+def _bits(x: float) -> bytes:
+    return struct.pack("<d", x)
+
+
+def _assert_same_result(got, want):
+    """Bit-for-bit equal statistics, and a timeline of the same objects
+    in the same order."""
+    assert type(got.makespan) is float
+    assert _bits(got.makespan) == _bits(want.makespan)
+    assert _bits(got.link_utilization) == _bits(want.link_utilization)
+    # repr of a dict of floats: same keys in the same order, same bits
+    assert repr(got.per_engine_stall) == repr(want.per_engine_stall)
+    assert repr(got.per_engine_busy) == repr(want.per_engine_busy)
+    assert got.summary() == want.summary()
+    assert len(got.timeline) == len(want.timeline)
+    assert all(a is b for a, b in zip(got.timeline, want.timeline))
+    assert got.timeline == want.timeline
+
+
+@pytest.mark.parametrize("seed", range(8))
+def test_link_result_matches_the_eager_result(seed):
+    """Two links with one mixed history of object and batch submissions:
+    a lazy ``result()`` after every submission, read only at the end,
+    equals the eager result taken at that point, and builds no
+    Transaction when it is taken."""
+    cfg, batches = _random_case(np.random.default_rng(2000 + seed))
+    batches = batches * 2
+    lazy, eager = LinkModel(cfg), LinkModel(cfg)
+    got, want = [], []
+    for i, spec in enumerate(batches):
+        for lm in (lazy, eager):
+            if (i + seed) % 3 == 0:
+                lm.submit(_txs(spec))
+            else:
+                lm.submit_batch(_batch(spec), TransactionLog())
+        built = materialized_tally()
+        got.append(lazy.result())
+        assert materialized_tally() == built
+        want.append(_eager_result(eager))
+    for g, w in zip(got, want):
+        # the two links built their own objects: compare them by value
+        assert [dataclasses.astuple(t) for t in g.timeline] == \
+            [dataclasses.astuple(t) for t in w.timeline]
+        w.timeline = list(g.timeline)
+        _assert_same_result(g, w)
+    # on one link, the lazy result aliases the objects the link holds
+    _assert_same_result(lazy.result(), _eager_result(lazy))
+
+
+def test_link_result_of_an_empty_link():
+    lm = LinkModel(CongestionConfig())
+    got = lm.result()
+    assert got.makespan == 0.0 and got.link_utilization == 0.0
+    assert len(got.timeline) == 0 and list(got.timeline) == []
+    _assert_same_result(got, _eager_result(lm))
+
+
+def test_link_result_timeline_is_a_snapshot():
+    """Batches and objects submitted after ``result()`` do not appear in
+    its timeline, whether it is first read before or after them; the
+    snapshot's objects are the link's own."""
+    cfg, batches = _random_case(np.random.default_rng(31))
+    lm, log = LinkModel(cfg), TransactionLog()
+    first = [_batch(spec) for spec in batches]
+    for b in first:
+        lm.submit_batch(b, log)
+    n = sum(len(b) for b in first)
+    r_late, r_early = lm.result(), lm.result()
+    assert all(b._txs is None for b in first), "result() materialized"
+    assert len(r_late.timeline) == n
+    early = list(r_early.timeline)
+    lm.submit_batch(_batch(batches[0]), log)
+    lm.submit(_txs(batches[-1]), log)
+    assert len(r_late.timeline) == len(list(r_late.timeline)) == n
+    assert all(a is b for a, b in zip(r_late.timeline, lm.timeline[:n]))
+    assert all(a is b for a, b in zip(early, r_late.timeline))
+    assert len(lm.result().timeline) > n
+
+
+def test_fabric_stats_match_the_eager_results():
+    """``FabricCluster.device_congestion()`` and the switch's port stats
+    on one small routed, congested fabric equal what eager link results
+    give, and neither builds a Transaction to get them."""
+    from repro.core import FABRIC_LINK, FabricCluster, ring
+    from repro.kernels.systolic_matmul.sweep import (matmul_backends,
+                                                     matmul_fabric_firmware)
+    fab = FabricCluster(4, congestion=CongestionConfig(dos_prob=0.1, seed=5),
+                        link_config=FABRIC_LINK, topology=ring(4))
+    fab.register_op("mm", **matmul_backends(tile=16, jit=False))
+    matmul_fabric_firmware(fab, "mm", "oracle", size=32, tile=16)
+    built = materialized_tally()
+    merged = fab.device_congestion()
+    ports = fab.switch.port_stats()
+    assert materialized_tally() == built
+    per = [(i, _eager_result(d.mem.link)) for i, d in enumerate(fab.devices)]
+    want = CongestionResult(
+        makespan=max(r.makespan for _, r in per),
+        per_engine_stall={f"d{i}/{e}": v for i, r in per
+                          for e, v in r.per_engine_stall.items()},
+        per_engine_busy={f"d{i}/{e}": v for i, r in per
+                         for e, v in r.per_engine_busy.items()},
+        link_utilization=sum(r.link_utilization for _, r in per) / len(per),
+        timeline=[t for _, r in per for t in r.timeline])
+    assert len(want.timeline) > 0
+    _assert_same_result(merged, want)
+    assert sum(p["flits"] for p in ports.values()) > 0
+    for p in fab.switch.ports:
+        r = _eager_result(p.link)
+        assert ports[p.label] == {
+            "stall": sum(r.per_engine_stall.values()),
+            "credit_stall": p.credit_stall,
+            "busy": sum(r.per_engine_busy.values()),
+            "flits": len(r.timeline)}
 
 
 # ---------------------------------------------------------------- simspeed

@@ -7,7 +7,7 @@ import jax
 import numpy as np
 import pytest
 
-from repro.core import CoVerifySession
+from repro.core import CongestionConfig, CoVerifySession
 from repro.core.spans import span
 from repro.kernels.systolic_matmul.sweep import (matmul_backends,
                                                  matmul_firmware)
@@ -59,6 +59,15 @@ def test_a_failing_sweep_times_its_bisection():
     assert rep.phase_seconds["bisect"] > 0.0
 
 
+def _host_events(trace_dir, name):
+    """Arguments of every host event called ``name`` in the trace."""
+    (path,) = trace_dir.glob("**/*.xplane.pb")
+    return [dict(ev.stats)
+            for plane in jax.profiler.ProfileData.from_file(
+                str(path)).planes if plane.name.startswith("/host:")
+            for line in plane.lines for ev in line.events if ev.name == name]
+
+
 def test_compare_span_counts_what_byte_equality_settled(tmp_path):
     """Both backends host-wrote ``a`` and ``b`` from one source, so the diff
     settles them by byte equality; ``c`` differs by an ulp and is diffed."""
@@ -79,11 +88,39 @@ def test_compare_span_counts_what_byte_equality_settled(tmp_path):
     (eq,) = rep.equivalence.values()
     inputs = 2 * 32 * 32
     assert eq.same_elems == inputs
-    (path,) = tmp_path.glob("**/*.xplane.pb")
-    compares = [dict(ev.stats)
-                for plane in jax.profiler.ProfileData.from_file(
-                    str(path)).planes if plane.name.startswith("/host:")
-                for line in plane.lines for ev in line.events
-                if ev.name == "fb.sweep.compare"]
+    compares = _host_events(tmp_path, "fb.sweep.compare")
     assert [(s["elems"], s["same_elems"]) for s in compares] == \
         [(3 * 32 * 32, inputs)]
+
+
+def test_collect_builds_no_transaction(tmp_path):
+    """A congested bridge cell collects its link statistics and counter
+    digest from the batches' columns and one values suffix per tick:
+    every link batch is still unmaterialized afterwards, and the collect
+    span counts the rows digested, the suffixes formatted and no
+    Transaction built."""
+    targets = []
+
+    def firmware(fb, op, backend, **kw):
+        targets.append(fb)
+        matmul_firmware(fb, op, backend, **kw)
+
+    sess = CoVerifySession(firmware,
+                           congestion=CongestionConfig(dos_prob=0.05, seed=7))
+    sess.register_op("mm", **matmul_backends(tile=16, jit=False))
+    sess.add_sweep("mm", ("oracle", "interpret"), [{"size": 32, "tile": 16}])
+    jax.profiler.start_trace(str(tmp_path))
+    rep = sess.run(max_workers=2)
+    jax.profiler.stop_trace()
+    assert rep.passed
+    assert all(r.congestion is not None and len(r.congestion.timeline) > 0
+               for r in rep.cells)
+    for fb in targets:
+        batches = fb.mem.link._tl_pending
+        assert batches and all(b._txs is None for b in batches)
+    collects = _host_events(tmp_path, "fb.cell.collect")
+    rows = sorted(fb.mem.counters.stream.n_samples for fb in targets)
+    assert sorted(c["samples"] for c in collects) == rows
+    assert all(0 < c["runs"] < c["samples"] for c in collects)
+    assert [c["materialized"] for c in collects] == [0, 0]
+    assert all(c["bytes"] > 0 for c in collects)
