@@ -119,7 +119,7 @@ def serving_storm():
     storm(clu)
     parity = all(single.requests[r].out_tokens == clu.requests[r].out_tokens
                  for r in prompts)
-    st = clu.fabric_stats()
+    st = clu.congestion_stats()
     print(f"  completed: single {single.completed}, "
           f"cluster {clu.completed} (placement "
           f"{dict(sorted(clu.placement.items()))})")

@@ -26,6 +26,7 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import hashlib
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import jax
@@ -37,6 +38,7 @@ from repro.core.counters import (CounterBank, CounterSpec,
                                  register_link_counters)
 from repro.core.registers import RegisterFile
 from repro.core.spans import span
+from repro.core.target import Channel, ProfileView
 from repro.core.transactions import (BurstBatch, OpMark, Transaction,
                                      TransactionLog, record_mark)
 
@@ -235,6 +237,41 @@ class MemoryBridge:
         (None when the bridge runs congestion-free)."""
         return self.link.result() if self.link is not None else None
 
+    def buffer_digests(self, prefix: str = "") -> Dict[str, str]:
+        """Short content digest of every buffer, by name: how state
+        summaries render DDR contents."""
+        return {f"{prefix}{n}": hashlib.sha256(
+                    np.ascontiguousarray(b.array).tobytes()).hexdigest()[:12]
+                for n, b in sorted(self.buffers.items())}
+
+    def channels(self, prefix: str = "",
+                 csr: Optional[RegisterFile] = None) -> List[Channel]:
+        """The profiler's channels: DDR (the online link, or the
+        fast-path clock less the transactions of ``csr``, which shares
+        this bridge's log), then ``csr``'s protocol clock."""
+        if self.link is not None:
+            ddr = Channel(f"{prefix}ddr", "link", self.link)
+        else:
+            ddr = Channel(f"{prefix}ddr", "clock", self, frozenset(
+                {csr.name} if csr is not None else ()))
+        return [ddr] + ([Channel(f"{prefix}csr", "csr", csr)]
+                        if csr is not None else [])
+
+    def fault_events(self) -> List:
+        plan = self.fault_plan
+        return list(plan.events) if plan is not None else []
+
+    # a bare bridge is a profiler target too (core/profiler.py)
+    def logs(self) -> List[TransactionLog]:
+        return [self.log]
+
+    def counter_banks(self) -> List[CounterBank]:
+        return [self.counters]
+
+    def profile_view(self) -> ProfileView:
+        return ProfileView(self.channels(),
+                           [(self.log, m) for m in self.marks])
+
     # --------------------------------------------- checkpoint/restore hooks
     def get_state(self) -> Dict[str, Any]:
         """Deep snapshot of the bridge at a transaction boundary
@@ -275,10 +312,12 @@ class FireBridge:
 
     Pass ``congestion`` to emulate interconnect contention online during
     ``launch()`` (§IV-C): stall statistics are then available from
-    ``congestion_stats()`` as soon as the firmware returns.
+    ``congestion_stats()`` as soon as the firmware returns.  A
+    co-verification target (core/target.py).
     """
 
     BACKENDS = ("oracle", "interpret", "compiled")
+    kind = "bridge"
 
     def __init__(self, name: str = "fb",
                  congestion: Optional[CongestionConfig] = None,
@@ -346,19 +385,54 @@ class FireBridge:
         for name, o in zip(out_bufs, outs):
             self.mem.dev_write(name, np.asarray(o), engine=f"{engine}_wr")
 
+    # ------------------------------------------- target contract members
+    def logs(self) -> List[TransactionLog]:
+        return [self.log]
+
+    def counter_banks(self) -> List[CounterBank]:
+        return [self.mem.counters]
+
+    @property
+    def modeled_time(self) -> float:
+        return self.mem.time
+
+    @property
+    def violations(self) -> List[str]:
+        return list(self.log.violations)
+
+    def fault_events(self) -> List:
+        return self.mem.fault_events()
+
+    def outputs(self) -> Dict[str, np.ndarray]:
+        """Final DDR state, every buffer copied."""
+        return {n: b.array.copy() for n, b in self.mem.buffers.items()}
+
     def congestion_stats(self) -> Optional[CongestionResult]:
         """Per-engine stall/busy/utilization accumulated online (Fig. 8)."""
         return self.mem.congestion_stats()
 
-    def counter_banks(self) -> List[CounterBank]:
-        """Always-on counter banks owned by this target, in stable order
-        (core/counters.py counter-diff oracle)."""
-        return [self.mem.counters]
+    def state_summary(self) -> Dict[str, Any]:
+        return {"time": round(self.mem.time, 6),
+                "buffers": self.mem.buffer_digests(),
+                "csr": {r.name: self.csr.hw_get(r.name)
+                        for r in self.csr._by_addr.values()},
+                "faults": len(self.log.faults),
+                "violations": len(self.log.violations)}
+
+    def profile_view(self, prefix: str = "") -> ProfileView:
+        """The DDR channel and the CSR protocol clock, names under
+        ``prefix`` (a fabric's ``d{i}/``), and the launches' op marks:
+        stall attribution closing to ``mem.time``."""
+        return ProfileView(self.mem.channels(prefix, self.csr),
+                           [(self.log, m) for m in self.mem.marks])
+
+    def cover(self, cov) -> None:
+        """Burst sizes and congestion outcomes of the log, and the fault
+        kinds injected."""
+        cov.hit_logs(self.logs())
+        cov.hit_faults(self.fault_events())
 
     def profiler(self, label: Optional[str] = None):
-        """Off-chip data-movement profile of everything logged so far
-        (core/profiler.py, §IV): exhaustive stall attribution closing to
-        ``mem.time``, per-engine/per-op series, Perfetto export."""
         from repro.core.profiler import DataMovementProfiler
         return DataMovementProfiler(self, label=label or self.name)
 

@@ -589,17 +589,3 @@ def simulate(txs: List[Transaction], cfg: CongestionConfig,
     lm = LinkModel(cfg)
     lm.submit(txs, log)
     return lm.result()
-
-
-def collective_stream_to_txs(collectives, time_scale: float = 1.0
-                             ) -> List[Transaction]:
-    """Adapt an hlo_profiler collective stream into congestion-model
-    transactions (engine = collective kind): stress-replays the compiled
-    program's communication schedule under contention."""
-    txs = []
-    t = 0.0
-    for c in collectives:
-        for r in range(min(c.multiplier, 1000)):    # cap replay length
-            txs.append(Transaction(t, c.kind, "read", 0, c.bytes_moved))
-            t += time_scale
-    return txs

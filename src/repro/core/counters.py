@@ -371,19 +371,13 @@ def register_switch_port_counters(bank: CounterBank, port) -> None:
 
 # --------------------------------------------------------------------------
 # Multi-bank helpers — the counter-diff oracle's unit of comparison is a
-# target's ordered bank list, mirroring replay.target_logs.
+# target's ordered bank list (core/target.py).
 # --------------------------------------------------------------------------
 
 def counter_banks(target) -> List[CounterBank]:
-    """Every counter bank a co-verification target owns, in a stable
-    order (the owner defines it via ``counter_banks()``).  Mirrors
-    ``replay.target_logs`` dispatch; targets predating the counter layer
-    simply contribute no banks."""
-    fn = getattr(target, "counter_banks", None)
-    if callable(fn):
-        return list(fn())
-    bank = getattr(target, "counters", None)
-    return [bank] if isinstance(bank, CounterBank) else []
+    """Every counter bank a co-verification target owns, in its stable
+    order."""
+    return list(target.counter_banks())
 
 
 def merged_digest(banks: Iterable[CounterBank]) -> str:

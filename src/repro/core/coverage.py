@@ -119,6 +119,20 @@ class CoverageModel:
         """Bucket one arbitrated transaction by its congestion outcome."""
         self.hit("congestion", "stalled" if stall > 0 else "free")
 
+    def hit_logs(self, logs) -> None:
+        """Bucket every transaction of ``logs`` by size and congestion
+        outcome."""
+        for log in logs:
+            for tx in log.txs:
+                self.hit_burst(tx.nbytes)
+                self.hit_congestion(tx.stall)
+
+    def hit_faults(self, events) -> None:
+        """Count the bridge-layer fault kinds among ``events``."""
+        for ev in events:
+            if ev.layer == "bridge":
+                self.hit("fault_kind", ev.kind)
+
     def hit_hops(self, n_hops: int) -> None:
         """Bucket one routed journey by its switch-hop count."""
         self.hit("hops", f"h{n_hops}" if n_hops < 3 else "h3plus")

@@ -47,7 +47,6 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
-import hashlib
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import numpy as np
@@ -59,6 +58,7 @@ from repro.core.counters import (CounterBank, CounterSpec,
                                  register_link_counters,
                                  register_switch_port_counters)
 from repro.core.switch import SwitchFabric
+from repro.core.target import Channel, ProfileView, log_digest
 from repro.core.topology import Topology, build_topology
 from repro.core.transactions import (BurstBatch, OpMark, Transaction,
                                      TransactionLog, record_mark)
@@ -105,7 +105,11 @@ class FabricCluster:
     instance (core/topology.py), or a builder name (``"ring"``,
     ``"torus2d"``, ``"fat_tree"``) applied to ``n_devices``.  ``None``
     keeps crossbar timing bit-exactly (golden traces).
+
+    A co-verification target (core/target.py).
     """
+
+    kind = "fabric"
 
     def __init__(self, n_devices: int, *, name: str = "fab",
                  congestion: Optional[CongestionConfig] = None,
@@ -350,8 +354,7 @@ class FabricCluster:
             b.tick(now)
 
     def counter_banks(self) -> List[CounterBank]:
-        """All cluster banks in stable order (fabric channels first, then
-        each device's DDR bank) — the counter-diff oracle's unit."""
+        """Fabric channels first, then each device's DDR bank."""
         return (list(self._counter_banks)
                 + [d.mem.counters for d in self.devices])
 
@@ -682,6 +685,45 @@ class FabricCluster:
         for b, s in zip(self._counter_banks, state.get("counters") or []):
             b.set_state(s)
 
+    # ------------------------------------------- target contract members
+    def logs(self) -> List[TransactionLog]:
+        return [self.log] + [d.log for d in self.devices]
+
+    @property
+    def modeled_time(self) -> float:
+        """The front of the fabric clock and every device's DDR clock."""
+        return max([self.time] + [d.mem.time for d in self.devices])
+
+    def state_summary(self) -> Dict[str, Any]:
+        out = {"time": round(self.time, 6),
+               "buffers": self.host.buffer_digests("host/")}
+        for i, d in enumerate(self.devices):
+            out["buffers"].update(d.mem.buffer_digests(f"d{i}/"))
+        out["violations"] = len(self.violations)
+        return out
+
+    def profile_view(self) -> ProfileView:
+        """The host channel, every port, every switch port (routed
+        fabrics: per-hop contention), then each device's channels; op
+        marks of every device, then of the fabric's collectives."""
+        chans = [Channel("fabric/host", "link", self.host_link)]
+        chans += [Channel(f"fabric/port{i}", "link", p)
+                  for i, p in enumerate(self.ports)]
+        if self.switch is not None:
+            chans += [Channel(f"fabric/{label}", "link", link)
+                      for label, link in self.switch.labeled_links()]
+        marks = []
+        for i, d in enumerate(self.devices):
+            view = d.profile_view(f"d{i}/")
+            chans += view.channels
+            marks += view.marks
+        marks += [(self.log, m) for m in self.marks]
+        return ProfileView(chans, marks)
+
+    def cover(self, cov) -> None:
+        """The fault kinds injected (bursts were hit as they moved)."""
+        cov.hit_faults(self.fault_events())
+
     # --------------------------------------------------------- diagnostics
     def link_stats(self) -> Dict[str, CongestionResult]:
         """Per-link Fig. 8 statistics: the host channel, every device
@@ -699,13 +741,10 @@ class FabricCluster:
                    for r in self.link_stats().values())
 
     def profiler(self, label: Optional[str] = None):
-        """Data-movement profile of the whole cluster (core/profiler.py):
-        one channel per fabric port plus the shared host channel and every
-        device's DDR/CSR, with per-collective-leg op attribution."""
         from repro.core.profiler import DataMovementProfiler
         return DataMovementProfiler(self, label=label or self.name)
 
-    def device_congestion(self) -> Optional[CongestionResult]:
+    def congestion_stats(self) -> Optional[CongestionResult]:
         """Merged per-device DDR-link statistics (engines prefixed
         ``d{i}/``), or None when the devices run congestion-free — so
         cross-scale sweeps keep reporting device-local memory stalls, not
@@ -746,13 +785,7 @@ class FabricCluster:
         return {n: b.array.copy() for n, b in self.host.buffers.items()}
 
     def digest(self) -> str:
-        """sha256 over the fabric log and every device log — the same-seed
-        reproducibility witness for multi-device runs."""
-        h = hashlib.sha256()
-        h.update(self.log.digest().encode())
-        for d in self.devices:
-            h.update(d.log.digest().encode())
-        return h.hexdigest()
+        return log_digest(self)
 
 
 def sharded_launch(fab: FabricCluster, op: str, backend: str, *,

@@ -611,13 +611,6 @@ class ProtocolFuzzer:
                "arrivals": self._run_arrivals}[scn.layer]
         return run(scn)
 
-    def _cover_log(self, log: TransactionLog) -> None:
-        """Feed one run's transaction stream into the burst-size and
-        congestion coverage bins."""
-        for tx in log.txs:
-            self.coverage.hit_burst(tx.nbytes)
-            self.coverage.hit_congestion(tx.stall)
-
     def _run_bridge(self, scn: Scenario) -> ScenarioResult:
         table = self._matmul_table()
         from repro.kernels.systolic_matmul import ops as mm_ops
@@ -648,7 +641,7 @@ class ProtocolFuzzer:
                               bk=self.TILE, dtype_bytes=4))
             outs[backend] = {n: b.array.copy()
                              for n, b in fb.mem.buffers.items()}
-            self._cover_log(fb.log)
+            self.coverage.hit_logs([fb.log])
             for ev in plan.events:
                 self.coverage.hit("fault_kind", ev.kind)
             if len(fb.log.faults) != len(plan.events):
@@ -739,7 +732,7 @@ class ProtocolFuzzer:
         # violation-path protocol bins come from the recorded expectations
         for ev in faults:
             self.coverage.hit("protocol", ev.kind)
-        self._cover_log(log)
+        self.coverage.hit_logs([log])
         if list(log.violations) != shadow.violations:
             failures.append(
                 f"violation audit mismatch: device {log.violations} != "
@@ -808,7 +801,7 @@ class ProtocolFuzzer:
                 self.coverage.hit("serving", ev.kind)
             elif ev.layer == "bridge":
                 self.coverage.hit("fault_kind", ev.kind)
-        self._cover_log(eng.mem.log)
+        self.coverage.hit_logs([eng.mem.log])
         faults = list(plan.events)
         n_bridge = sum(1 for e in faults if e.layer == "bridge")
         if len(eng.mem.log.faults) != n_bridge:
@@ -916,11 +909,9 @@ class ProtocolFuzzer:
         if pool.pages:
             failures.append(f"requests still hold pages after drain: "
                             f"{sorted(pool.pages)}")
-        self._cover_log(eng.mem.log)
+        self.coverage.hit_logs([eng.mem.log])
         faults = list(plan.events)
-        for ev in faults:
-            if ev.layer == "bridge":
-                self.coverage.hit("fault_kind", ev.kind)
+        self.coverage.hit_faults(faults)
         tokens = [(rid, tuple(eng.requests[rid].out_tokens))
                   for rid in sorted(feasible) if rid in eng.requests]
         return ScenarioResult(

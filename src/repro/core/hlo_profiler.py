@@ -129,12 +129,6 @@ class Profile:
     per_comp_mult: Dict[str, int]
     dots: List[DotRecord] = dataclasses.field(default_factory=list)
 
-    def top_dots(self, n: int = 15) -> List[DotRecord]:
-        return sorted(self.dots, key=lambda d: -d.total_flops)[:n]
-
-    def top_collectives(self, n: int = 15) -> List[CollectiveRecord]:
-        return sorted(self.collectives, key=lambda c: -c.total_bytes)[:n]
-
     def collective_summary(self) -> Dict[str, Tuple[int, float]]:
         agg: Dict[str, List[float]] = defaultdict(lambda: [0, 0.0])
         for c in self.collectives:

@@ -55,9 +55,8 @@ from collections import defaultdict
 from pathlib import Path
 from typing import Any, Dict, List, Optional, Tuple
 
-from repro.core.bridge import FireBridge, MemoryBridge
-from repro.core.congestion import CongestionConfig, LinkModel
-from repro.core.fabric import FabricCluster
+from repro.core.congestion import CongestionConfig
+from repro.core.target import Channel
 from repro.core.transactions import OpMark, Transaction, TransactionLog
 
 __all__ = [
@@ -110,10 +109,6 @@ class StallBreakdown:
             cycles[carrier] = math.nextafter(
                 cycles[carrier], math.inf if s < total else -math.inf)
         return cls(total, cycles)
-
-    def fractions(self) -> Dict[str, float]:
-        t = self.total or 1.0
-        return {c: self.cycles[c] / t for c in CATEGORIES}
 
     def rows(self) -> List[str]:
         """category,cycles,percent rows (taxonomy order)."""
@@ -207,13 +202,14 @@ def _overlap(busy: List[Tuple[float, float]],
     return tot
 
 
-def _profile_link(name: str, link: LinkModel) -> ChannelProfile:
+def _profile_link(ch: Channel) -> ChannelProfile:
     """Attribute a congestion-arbitrated channel (§IV-C): walk the link's
     arbitration-order timeline reconstructing each burst's issue/start
     from its recorded fields, classify every idle gap (compute vs
     fault-delay vs serialization, layered by what was holding the burst
     back), overlay waiting demand onto busy time (contention), and close
     the partition with the transfer remainder."""
+    name, link = ch.name, ch.source
     cfg = link.cfg
     idle = {"compute": 0.0, "serialization": 0.0, "fault_delay": 0.0}
     dos_total = 0.0
@@ -265,13 +261,13 @@ def _profile_link(name: str, link: LinkModel) -> ChannelProfile:
                           list(timeline), cfg, residual)
 
 
-def _profile_clock(name: str, mem: MemoryBridge,
-                   exclude_engines: frozenset) -> ChannelProfile:
+def _profile_clock(ch: Channel) -> ChannelProfile:
     """Attribute a fast-path (congestion-free) bridge: one logical cycle
     of transfer per access; clock jumps beyond that are fault delay (up
     to the burst's recorded ``fault_delay``) and compute overlap
     (min-issue times ahead of the clock)."""
-    txs = [t for t in mem.log.txs if t.engine not in exclude_engines]
+    name, mem = ch.name, ch.source
+    txs = [t for t in mem.log.txs if t.engine not in ch.exclude]
     idle = {"compute": 0.0, "fault_delay": 0.0}
     engines: Dict[str, EngineStats] = defaultdict(EngineStats)
     prev = 0.0
@@ -295,9 +291,10 @@ def _profile_clock(name: str, mem: MemoryBridge,
                           None, residual)
 
 
-def _profile_csr(name: str, csr: Any) -> ChannelProfile:
+def _profile_csr(ch: Channel) -> ChannelProfile:
     """Attribute a register-protocol clock (§IV-A): every ``fb_read_32``/
     ``fb_write_32`` is one protocol tick of pure transfer."""
+    name, csr = ch.name, ch.source
     txs = [t for t in csr.log.txs if t.engine == csr.name]
     engines: Dict[str, EngineStats] = defaultdict(EngineStats)
     for tx in txs:
@@ -313,42 +310,9 @@ def _profile_csr(name: str, csr: Any) -> ChannelProfile:
                           None, residual)
 
 
-def _bridge_channels(prefix: str, fb: FireBridge) -> List[ChannelProfile]:
-    mem, csr = fb.mem, fb.csr
-    if mem.link is not None:
-        ddr = _profile_link(f"{prefix}ddr", mem.link)
-    else:
-        ddr = _profile_clock(f"{prefix}ddr", mem, frozenset({csr.name}))
-    return [ddr, _profile_csr(f"{prefix}csr", csr)]
-
-
-def _is_cluster_serving(target: Any) -> bool:
-    return hasattr(target, "engines") and hasattr(target, "csr")
-
-
-def _is_serving(target: Any) -> bool:
-    return hasattr(target, "slots") and hasattr(target, "step")
-
-
-def _request_spans(engines) -> List[dict]:
-    """Completed continuous-batching request lifecycles as (queue,
-    prefill, decode) spans in modeled cycles.  Captured at profiler
-    construction — the profiler does not retain its target — from the
-    ``(device, engine)`` pairs given.  Storm-mode requests carry no
-    admission stamps (t_admit == -1) and are skipped, so legacy serving
-    profiles are unchanged."""
-    spans = []
-    for dev, eng in engines:
-        for rid, req in eng.requests.items():
-            if req.t_admit < 0 or req.t_done < 0:
-                continue                # storm-mode or still in flight
-            spans.append({"rid": int(rid), "device": int(dev),
-                          "t_submit": float(req.t_submit),
-                          "t_admit": float(req.t_admit),
-                          "t_first": float(req.t_first),
-                          "t_done": float(req.t_done),
-                          "tokens": len(req.out_tokens)})
-    return sorted(spans, key=lambda s: (s["t_submit"], s["rid"]))
+# how each kind of channel in a target's ``profile_view`` is attributed
+_PROFILE = {"link": _profile_link, "clock": _profile_clock,
+            "csr": _profile_csr}
 
 
 # ------------------------------------------------------------ the profiler
@@ -363,99 +327,34 @@ class DataMovementProfiler:
         prof.breakdown()["ddr"].cycles         # closes to fb.mem.time
         prof.save_perfetto("run.trace.json")   # open in ui.perfetto.dev
 
-    Accepted targets: ``FireBridge``/``MemoryBridge`` (one DDR channel +
-    the CSR protocol clock), ``FabricCluster`` (host↔fabric channel,
-    every port, every device), ``ServingEngine`` / ``ClusterServingEngine``
-    (prompt-upload vs token-writeback traffic), and — via
+    Accepted targets: every co-verification target (core/target.py —
+    the channels, op marks and request lifecycles of its
+    ``profile_view()``), a bare ``MemoryBridge``, and — via
     ``profile_recording`` — any replayed ``Recording``.
     """
 
     def __init__(self, target: Any, label: str = "run") -> None:
         self.label = label
-        self.channels: List[ChannelProfile] = []
-        self.marks: List[Tuple[TransactionLog, OpMark]] = []
-        # serving targets only: completed request lifecycles (see
-        # _request_spans); empty for bridge/fabric targets
-        self.requests: List[dict] = []
         # resolve eagerly and do NOT retain the target: channels/marks
         # alias only logs and link timelines, so a profiled sweep cell
         # does not pin its bridge's DDR buffers for the report's lifetime
-        self._resolve(target)
+        view = target.profile_view()
+        self.channels: List[ChannelProfile] = [_PROFILE[c.kind](c)
+                                               for c in view.channels]
+        self.marks: List[Tuple[TransactionLog, OpMark]] = view.marks
+        # serving targets only: completed request lifecycles
+        # (serving.engine.request_spans); empty for bridge/fabric targets
+        self.requests: List[dict] = view.requests
+        self._primary_log = target.logs()[0]
         self._by_name = {c.name: c for c in self.channels}
         # sampled counter streams (core/counters.py), snapshotted as
         # plain tuples — bank probes close over the target, so retaining
         # the banks themselves would break the no-pin discipline above
-        from repro.core.counters import counter_banks as _banks_of
         self.counter_tracks: List[Tuple[str, List[Tuple[str, str]],
                                         List[float], List[tuple]]] = [
             (b.name, [(s.name, s.unit) for s in b.specs],
              list(b.stream.times), list(b.stream.rows))
-            for b in _banks_of(target)]
-
-    # ---------------------------------------------------------- resolution
-    def _resolve(self, target: Any) -> None:
-        if isinstance(target, FabricCluster):
-            self.channels.append(_profile_link("fabric/host",
-                                               target.host_link))
-            for i, p in enumerate(target.ports):
-                self.channels.append(_profile_link(f"fabric/port{i}", p))
-            if target.switch is not None:
-                # routed fabric: one channel (and Perfetto track) per
-                # switch port — per-hop contention attribution
-                for label, link in target.switch.labeled_links():
-                    self.channels.append(
-                        _profile_link(f"fabric/{label}", link))
-            for i, d in enumerate(target.devices):
-                self.channels.extend(_bridge_channels(f"d{i}/", d))
-                self.marks.extend((d.log, m) for m in d.mem.marks)
-            self.marks.extend((target.log, m) for m in target.marks)
-            self._primary_log = target.log
-            return
-        if isinstance(target, FireBridge):
-            self.channels.extend(_bridge_channels("", target))
-            self.marks.extend((target.log, m) for m in target.mem.marks)
-            self._primary_log = target.log
-            return
-        if isinstance(target, MemoryBridge):
-            if target.link is not None:
-                self.channels.append(_profile_link("ddr", target.link))
-            else:
-                self.channels.append(_profile_clock("ddr", target,
-                                                    frozenset()))
-            self.marks.extend((target.log, m) for m in target.marks)
-            self._primary_log = target.log
-            return
-        if _is_cluster_serving(target):
-            self.channels.append(_profile_link("host", target.host_link))
-            self.channels.append(_profile_csr("csr", target.csr))
-            sw = getattr(target, "switch", None)
-            if sw is not None:
-                for label, link in sw.labeled_links():
-                    self.channels.append(_profile_link(f"sw/{label}",
-                                                       link))
-            for i, eng in enumerate(target.engines):
-                if eng.mem.link is not None:
-                    self.channels.append(
-                        _profile_link(f"e{i}/ddr", eng.mem.link))
-                else:
-                    self.channels.append(_profile_clock(
-                        f"e{i}/ddr", eng.mem, frozenset({eng.csr.name})))
-                self.channels.append(_profile_csr(f"e{i}/csr", eng.csr))
-            self.requests = _request_spans(enumerate(target.engines))
-            self._primary_log = target.log
-            return
-        if _is_serving(target):
-            if target.mem.link is not None:
-                self.channels.append(_profile_link("ddr", target.mem.link))
-            else:
-                self.channels.append(_profile_clock(
-                    "ddr", target.mem, frozenset({target.csr.name})))
-            self.channels.append(_profile_csr("csr", target.csr))
-            self.requests = _request_spans([(0, target)])
-            self._primary_log = target.mem.log
-            return
-        raise TypeError(f"no profiling mapping for "
-                        f"{type(target).__name__}")
+            for b in target.counter_banks()]
 
     # ------------------------------------------------------------- queries
     def channel(self, name: str) -> ChannelProfile:
@@ -754,9 +653,8 @@ def profile_window(target: Any, rec: Any, lo: int, hi: int
     fault delay) — the wall-partition categories need the full horizon
     and are reported by ``DataMovementProfiler`` on full-range targets.
     """
-    from repro.core import replay as rp
     out: Dict[str, Dict[str, float]] = {}
-    for li, log in enumerate(rp.target_logs(target)):
+    for li, log in enumerate(target.logs()):
         marks = rec.tx_marks[li]
         for tx in log.txs[marks[lo]:marks[hi]]:
             e = out.setdefault(tx.engine, {

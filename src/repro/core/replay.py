@@ -64,6 +64,7 @@ import numpy as np
 
 from repro.core.bridge import FireBridge
 from repro.core.fabric import FabricCluster
+from repro.core.target import log_digest
 from repro.core.transactions import TransactionLog
 
 __all__ = [
@@ -71,7 +72,7 @@ __all__ = [
     "DebugSession", "Recorder", "RecordingBridge", "DivergenceReport",
     "bisect_divergence", "record_serving_storm", "serving_storm_program",
     "record_open_loop", "open_loop_program",
-    "apply_event", "target_logs", "state_summary", "window_report",
+    "apply_event", "target_logs", "window_report",
 ]
 
 
@@ -219,26 +220,10 @@ class ReplayWindow:
 
 
 # ------------------------------------------------------- state inspection
-def _is_cluster_serving(target: Any) -> bool:
-    return hasattr(target, "engines") and hasattr(target, "csr")
-
-
-def _is_serving(target: Any) -> bool:
-    return hasattr(target, "slots") and hasattr(target, "step")
-
-
 def target_logs(target: Any) -> List[TransactionLog]:
     """The target's transaction logs in canonical order (the order golden
-    trace files concatenate them)."""
-    if isinstance(target, FireBridge):
-        return [target.log]
-    if isinstance(target, FabricCluster):
-        return [target.log] + [d.log for d in target.devices]
-    if _is_cluster_serving(target):
-        return [target.log] + [e.mem.log for e in target.engines]
-    if _is_serving(target):
-        return [target.mem.log]
-    raise TypeError(f"no replay log mapping for {type(target).__name__}")
+    trace files concatenate them; core/target.py)."""
+    return target.logs()
 
 
 def _hash_update(h: "hashlib._Hash", v: Any) -> None:
@@ -322,53 +307,6 @@ def functional_fingerprint(state: Dict[str, Any]) -> str:
     return _fingerprint(state, _TIMING_KEYS)
 
 
-def state_summary(target: Any) -> Dict[str, Any]:
-    """Small human-facing excerpt of the target's architectural state —
-    what a divergence report prints as "surrounding device state"."""
-    def bufs(mem, prefix=""):
-        return {f"{prefix}{n}": hashlib.sha256(
-                    np.ascontiguousarray(b.array).tobytes()).hexdigest()[:12]
-                for n, b in sorted(mem.buffers.items())}
-
-    if isinstance(target, FireBridge):
-        return {"time": round(target.mem.time, 6),
-                "buffers": bufs(target.mem),
-                "csr": {r.name: target.csr.hw_get(r.name)
-                        for r in target.csr._by_addr.values()},
-                "faults": len(target.log.faults),
-                "violations": len(target.log.violations)}
-    if isinstance(target, FabricCluster):
-        out = {"time": round(target.time, 6), "buffers": bufs(target.host,
-                                                             "host/")}
-        for i, d in enumerate(target.devices):
-            out["buffers"].update(bufs(d.mem, f"d{i}/"))
-        out["violations"] = len(target.violations)
-        return out
-    if _is_cluster_serving(target):
-        out = {"time": round(target.time, 6), "buffers": bufs(target.mem),
-               "completed": target.completed,
-               "tokens": {rid: list(r.out_tokens)
-                          for rid, r in sorted(target.requests.items())},
-               "violations": len(target.violations)}
-        pools = {f"e{i}": e.kv_pool.n_free
-                 for i, e in enumerate(target.engines)
-                 if getattr(e, "kv_pool", None) is not None}
-        if pools:
-            out["kv_free_pages"] = pools
-        return out
-    if _is_serving(target):
-        out = {"time": round(target.mem.time, 6),
-               "buffers": bufs(target.mem),
-               "completed": target.completed,
-               "tokens": {rid: list(r.out_tokens)
-                          for rid, r in sorted(target.requests.items())},
-               "violations": len(target.mem.log.violations)}
-        if getattr(target, "kv_pool", None) is not None:
-            out["kv_free_pages"] = {"e0": target.kv_pool.n_free}
-        return out
-    raise TypeError(f"no replay summary for {type(target).__name__}")
-
-
 # --------------------------------------------------------- event execution
 def _apply_bridge(fb: FireBridge, ev: TimelineEvent) -> Any:
     k, a = ev.kind, ev.args
@@ -449,18 +387,17 @@ def _apply_serving(eng: Any, ev: TimelineEvent) -> Any:
     raise ValueError(f"unknown serving event kind {k!r}")
 
 
+# the event vocabulary of each target kind (core/target.py)
+_APPLY = {"bridge": _apply_bridge, "fabric": _apply_fabric,
+          "serving": _apply_serving}
+
+
 def apply_event(target: Any, ev: TimelineEvent) -> Any:
     """Execute ONE timeline event against a live target.  Record and
     replay both funnel through here, so the two cannot drift."""
     if ev.kind == "call":                  # escape hatch: fn(target, *args)
         return ev.args[0](target, *ev.args[1:])
-    if isinstance(target, FireBridge):
-        return _apply_bridge(target, ev)
-    if isinstance(target, FabricCluster):
-        return _apply_fabric(target, ev)
-    if _is_cluster_serving(target) or _is_serving(target):
-        return _apply_serving(target, ev)
-    raise TypeError(f"no replay apply for {type(target).__name__}")
+    return _APPLY[target.kind](target, ev)
 
 
 # ------------------------------------------------------------- the session
@@ -475,7 +412,7 @@ class Recorder:
         self.session = session
         self.target = target
         self.rec = rec
-        self.logs = target_logs(target)
+        self.logs = target.logs()
         self._cursors = [log.cursor() for log in self.logs]
         # construction-time lines (e.g. congestion_perturb at bridge init)
         for log in self.logs:
@@ -559,10 +496,7 @@ class DebugSession:
         final = recorder.checkpoint()
         rec.final_fingerprint = final.fingerprint
         rec.final_func_fingerprint = final.func_fingerprint
-        h = hashlib.sha256()
-        for log in recorder.logs:
-            h.update(log.digest().encode())
-        rec.log_digest = h.hexdigest()
+        rec.log_digest = log_digest(target)
         rec.target = target
         return rec
 
@@ -583,7 +517,7 @@ class DebugSession:
         target.set_state(ck.state)
         self.replays += 1
         rec.replays += 1
-        logs = target_logs(target)
+        logs = target.logs()
         cursors = [log.cursor() for log in logs]
         ops: List[OpTrace] = []
         for i in range(ck.op_index, hi):
@@ -598,7 +532,7 @@ class DebugSession:
                 state = target.get_state()
                 ops.append(OpTrace(i, ev, lines,
                                    functional_fingerprint(state),
-                                   state_summary(target)))
+                                   target.state_summary()))
         return ReplayWindow(lo, hi, ops, target, ck.op_index)
 
 
@@ -699,10 +633,8 @@ def serving_storm_program(reqs: Sequence[Tuple[int, Sequence[int], int]],
             rec.do("csr_write", "SUBMIT_MAXNEW", int(mx))
             rec.do("csr_write", "DOORBELL", 1)
             rec.checkpoint()
-        pending = (eng._n_pending if _is_cluster_serving(eng)
-                   else lambda: len(eng.pending))
         for _ in range(max_ticks):
-            if not pending() and not eng._n_active():
+            if not eng._n_pending() and not eng._n_active():
                 break
             rec.do("step")
 

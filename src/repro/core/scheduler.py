@@ -165,11 +165,13 @@ class SweepCell:
         return f"{self.op}[{cfg}]@{self.backend}{dev}{topo}"
 
     @property
-    def timing_label(self) -> str:
-        """Backend-FREE cell identity: the fault-fork label for serving
-        cells, so one configuration's fault stream — and therefore its SLO
-        rows and log digest — is identical across backends (the
-        determinism tier in tests/test_serving_slo.py diffs them)."""
+    def fork_label(self) -> str:
+        """What the cell's fault plan forks by: ``label`` for firmware
+        cells (each backend draws its own fault stream), a backend-FREE
+        identity for serving cells (one stream per configuration, so SLO
+        rows and log digests compare across backends)."""
+        if self.serving is None:
+            return self.label
         cfg = ",".join(f"{k}={v}" for k, v in sorted(self.config.items()))
         return f"{self.op}[{cfg}]x{self.devices}dev"
 
@@ -501,211 +503,106 @@ class CoVerifySession:
                 for t in (topologies if n > 1 else (None,))]
 
     # ----------------------------------------------------------- execute
+    def _make_target(self, cell: SweepCell, profile: bool = False,
+                     coverage: Optional[CoverageModel] = None) -> Any:
+        """Build one cell's target (core/target.py), for the run and for
+        its bisection alike: the registered serving target, a
+        FabricCluster (devices > 1, or a session with fabric firmware) or
+        a FireBridge.  The target gets its own child of the cell's fault
+        plan, forked by ``fork_label``: thread-pool order cannot perturb
+        the fault stream, and a re-recording draws the same one."""
+        plan = (cell.fault_plan.fork(cell.fork_label)
+                if cell.fault_plan is not None else None)
+        if cell.serving is not None:
+            return self._serving_factory(cell.backend, cell.devices, plan)
+        if cell.devices > 1 or self.fabric_firmware is not None:
+            target = FabricCluster(
+                cell.devices, congestion=cell.congestion,
+                link_config=self.link_config, fault_plan=plan,
+                profile=profile, topology=cell.topology, coverage=coverage)
+        else:
+            target = FireBridge(congestion=cell.congestion, fault_plan=plan,
+                                profile=profile)
+        target.register_op(cell.op, **self._ops[cell.op])
+        return target
+
+    def _program(self, cell: SweepCell, target: Any,
+                 coverage: Optional[CoverageModel]) -> Any:
+        """Run the cell's program on its target: the firmware, or for a
+        serving cell the open-loop run of its arrival trace, whose SLO
+        report is returned."""
+        if cell.serving is None:
+            # fabric firmware, when given, is what built a FabricCluster
+            fw = self.fabric_firmware or self.firmware
+            fw(target, cell.op, cell.backend, **cell.config)
+            return None
+        from repro.serving.arrivals import run_open_loop
+        from repro.serving.slo import SLOReport
+        if coverage is not None:
+            coverage.hit("arrivals", cell.serving.kind)
+        run_open_loop(target, cell.serving)
+        return SLOReport.from_run(cell.serving, target, label=cell.label)
+
     def _run_cell(self, cell: SweepCell, sweep: int = 0) -> CellResult:
         """One cell inside an ``fb.cell`` span, which carries the run
-        number of its sweep: a pool thread's spans join the caller's."""
+        number of its sweep: a pool thread's spans join the caller's.
+        The target is built and the program run in ``fb.firmware``; the
+        ``fb.cell.collect`` span then reads the result through the target
+        contract alone."""
         with span("fb.cell", sweep=sweep, cell=cell.label):
-            if cell.serving is not None:
-                return self._run_serving_cell(cell)
-            # each cell forks its own child plan keyed by the cell label,
-            # so thread-pool scheduling order cannot perturb the fault
-            # stream
-            plan = (cell.fault_plan.fork(cell.label)
-                    if cell.fault_plan is not None else None)
-            if cell.devices > 1 or self.fabric_firmware is not None:
-                return self._run_fabric_cell(cell, plan)
-            return self._run_bridge_cell(cell, plan)
+            cov = CoverageModel() if self.coverage is not None else None
+            err: Optional[str] = None
+            slo = None
+            with span("fb.firmware") as fw:
+                target = self._make_target(cell, self.profile, cov)
+                try:
+                    slo = self._program(cell, target, cov)
+                except Exception as e:    # cell failure must not kill sweep
+                    err = f"{type(e).__name__}: {e}"
+            with _collect_span() as col:
+                if cov is not None:
+                    target.cover(cov)
+                outputs = target.outputs()
+                col.set(bytes=_nbytes(outputs))
+                return CellResult(
+                    cell=cell,
+                    outputs=outputs,
+                    seconds=fw.seconds,
+                    bridge_time=target.modeled_time,
+                    congestion=target.congestion_stats(),
+                    violations=target.violations,
+                    error=err,
+                    faults=target.fault_events(),
+                    links=(target.link_stats() if target.kind == "fabric"
+                           else None),
+                    profile=(target.profiler(cell.label) if self.profile
+                             else None),
+                    coverage=cov,
+                    slo=slo,
+                    counters=(self._cell_counters(target, cell)
+                              if err is None else None),
+                )
 
     @staticmethod
-    def _run_firmware(fw: Callable[..., None], target: Any, cell: SweepCell
-                      ) -> Tuple[float, Optional[str]]:
-        """Run the firmware in an ``fb.firmware`` span: (its seconds, the
-        error it raised or None)."""
-        err: Optional[str] = None
-        with span("fb.firmware") as s:
-            try:
-                fw(target, cell.op, cell.backend, **cell.config)
-            except Exception as e:        # cell failure must not kill sweep
-                err = f"{type(e).__name__}: {e}"
-        return s.seconds, err
-
-    def _run_bridge_cell(self, cell: SweepCell,
-                         plan: Optional[FaultPlan]) -> CellResult:
-        """One single-device cell on a fresh FireBridge."""
-        cov = CoverageModel() if self.coverage is not None else None
-        fb = FireBridge(congestion=cell.congestion, fault_plan=plan,
-                        profile=self.profile)
-        fb.register_op(cell.op, **self._ops[cell.op])
-        dt, err = self._run_firmware(self.firmware, fb, cell)
-        with _collect_span() as col:
-            if cov is not None:
-                self._feed_coverage(cov, fb.log, plan)
-            outputs = {n: b.array.copy() for n, b in fb.mem.buffers.items()}
-            col.set(bytes=_nbytes(outputs))
-            return CellResult(
-                cell=cell,
-                outputs=outputs,
-                seconds=dt,
-                bridge_time=fb.mem.time,
-                congestion=fb.congestion_stats(),
-                violations=list(fb.log.violations),
-                error=err,
-                faults=list(plan.events) if plan is not None else [],
-                profile=fb.profiler(cell.label) if self.profile else None,
-                coverage=cov,
-                counters=(self._cell_counters(
-                    fb, cell, cell.label if plan is not None else None)
-                    if err is None else None),
-            )
-
-    @staticmethod
-    def _cell_counters(target: Any, cell: SweepCell,
-                       fork_label: Optional[str]) -> Dict[str, Any]:
+    def _cell_counters(target: Any, cell: SweepCell) -> Dict[str, Any]:
         """Counter-diff oracle payload of one finished cell
         (core/counters.py).  ``timing_key`` gates the full-stream digest
-        comparison: streams are only required to be identical among cells
-        with the same device count, topology, congestion seed, and fault
-        fork (firmware cells fork their fault stream by the
-        backend-DEPENDENT label, so fault-injected firmware streams
-        legitimately differ per backend; serving cells fork by the
-        backend-free timing label and stay comparable).  The functional
-        digest has no such gate — retired tokens/requests/doorbells are
-        invariant across backends AND scales."""
+        comparison: streams need only agree among cells with the same
+        device count, topology, congestion seed and fault fork
+        (``SweepCell.fork_label``).  The functional digest has no such
+        gate — retired tokens/requests/doorbells are invariant across
+        backends AND scales."""
         from repro.core import counters as cc
-        banks = cc.counter_banks(target)
+        banks = target.counter_banks()
         return {
             "digest": cc.merged_digest(banks),
             "totals": cc.merged_totals(banks),
             "functional": cc.functional_digest(banks),
             "timing_key": (cell.devices, cell._topo_kind,
-                           repr(cell.congestion), fork_label),
+                           repr(cell.congestion),
+                           cell.fork_label if cell.fault_plan is not None
+                           else None),
         }
-
-    @staticmethod
-    def _feed_coverage(cov: CoverageModel, log, plan: Optional[FaultPlan],
-                       ) -> None:
-        """Feed one finished cell's transaction stream + fault trace into
-        its private coverage model (burst/congestion/fault-kind bins)."""
-        for tx in log.txs:
-            cov.hit_burst(tx.nbytes)
-            cov.hit_congestion(tx.stall)
-        for ev in (plan.events if plan is not None else []):
-            if ev.layer == "bridge":
-                cov.hit("fault_kind", ev.kind)
-
-    def _run_serving_cell(self, cell: SweepCell) -> CellResult:
-        """One open-loop serving cell: build the target via the registered
-        factory, drive the arrival trace through the shared decision loop,
-        and collect the SLO report.  The fault plan forks by the
-        backend-FREE ``timing_label`` — one configuration has ONE fault
-        stream, so SLO rows and log digests are comparable across
-        backends (the determinism tier's contract)."""
-        from repro.core.replay import target_logs
-        from repro.serving.arrivals import run_open_loop
-        from repro.serving.slo import SLOReport
-        trace = cell.serving
-        plan = (cell.fault_plan.fork(cell.timing_label)
-                if cell.fault_plan is not None else None)
-        cov = CoverageModel() if self.coverage is not None else None
-        err: Optional[str] = None
-        slo = None
-        # the open-loop run stands in for this cell's firmware
-        with span("fb.firmware") as fw:
-            target = self._serving_factory(cell.backend, cell.devices, plan)
-            try:
-                run_open_loop(target, trace)
-                slo = SLOReport.from_run(trace, target, label=cell.label)
-            except Exception as e:        # cell failure must not kill sweep
-                err = f"{type(e).__name__}: {e}"
-        with _collect_span() as col:
-            violations = (list(target.violations)
-                          if hasattr(target, "violations")
-                          else list(target.mem.log.violations))
-            if cov is not None:
-                for log in target_logs(target):
-                    for tx in log.txs:
-                        cov.hit_burst(tx.nbytes)
-                        cov.hit_congestion(tx.stall)
-                self._feed_arrival_coverage(cov, trace, target, violations)
-            # the equivalence payload: every completed request's token
-            # stream, compared exactly across backends and device counts
-            outputs = {f"tokens[{rid}]": np.asarray(req.out_tokens,
-                                                    np.int64)
-                       for rid, req in sorted(target.requests.items())
-                       if req.done}
-            col.set(bytes=_nbytes(outputs))
-            return CellResult(
-                cell=cell,
-                outputs=outputs,
-                seconds=fw.seconds,
-                bridge_time=float(target.clock),
-                congestion=target.congestion_stats(),
-                violations=violations,
-                error=err,
-                faults=list(plan.events) if plan is not None else [],
-                profile=(target.profiler(cell.label) if self.profile
-                         else None),
-                coverage=cov,
-                slo=slo,
-                counters=(self._cell_counters(
-                    target, cell,
-                    cell.timing_label if plan is not None else None)
-                    if err is None else None),
-            )
-
-    @staticmethod
-    def _feed_arrival_coverage(cov: CoverageModel, trace: Any, target: Any,
-                               violations: List[str]) -> None:
-        """Arrival/admission coverage bins of one serving cell."""
-        cov.hit("arrivals", trace.kind)
-        engines = getattr(target, "engines", None) or [target]
-        pools = [e.kv_pool for e in engines
-                 if getattr(e, "kv_pool", None) is not None]
-        deferrals = sum(p.deferrals for p in pools)
-        if deferrals:
-            cov.hit("arrivals", "deferred", deferrals)
-        if any(p.peak_in_use == p.n_pages for p in pools):
-            cov.hit("arrivals", "pool_full")
-        if any("exceeds KV page pool" in v for v in violations):
-            cov.hit("arrivals", "infeasible_reject")
-
-    def _run_fabric_cell(self, cell: SweepCell,
-                         plan: Optional[FaultPlan]) -> CellResult:
-        """One cell on a FabricCluster: the firmware shards the op across
-        ``cell.devices`` devices and the *host-visible gathered state* is
-        what enters the cross-scale equivalence group."""
-        cov = CoverageModel() if self.coverage is not None else None
-        fab = FabricCluster(cell.devices, congestion=cell.congestion,
-                            link_config=self.link_config, fault_plan=plan,
-                            profile=self.profile, topology=cell.topology,
-                            coverage=cov)
-        fab.register_op(cell.op, **self._ops[cell.op])
-        dt, err = self._run_firmware(self.fabric_firmware or self.firmware,
-                                     fab, cell)
-        with _collect_span() as col:
-            if cov is not None:
-                for ev in fab.fault_events():
-                    if ev.layer == "bridge":
-                        cov.hit("fault_kind", ev.kind)
-            outputs = fab.outputs()
-            col.set(bytes=_nbytes(outputs))
-            return CellResult(
-                cell=cell,
-                outputs=outputs,
-                seconds=dt,
-                bridge_time=max([fab.time]
-                                + [d.mem.time for d in fab.devices]),
-                congestion=fab.device_congestion(),
-                violations=fab.violations,
-                error=err,
-                faults=fab.fault_events(),
-                links=fab.link_stats(),
-                profile=fab.profiler(cell.label) if self.profile else None,
-                coverage=cov,
-                counters=(self._cell_counters(
-                    fab, cell, cell.label if plan is not None else None)
-                    if err is None else None),
-            )
 
     def run(self, max_workers: Optional[int] = None,
             tol: float = 1e-3, bisect_failures: bool = True) -> SweepReport:
@@ -856,55 +753,33 @@ class CoVerifySession:
         return {"pair": pair, "kind": kind,
                 "totals": {m: rs[m].counters["totals"] for m in pair}}
 
+    def _record_cell(self, cell: SweepCell, checkpoint_interval: int = 8):
+        """Re-record one cell on a target ``_make_target`` rebuilds (same
+        fault fork and congestion link, no profiling): (session,
+        recording), bit-for-bit the sweep's run.  Firmware runs behind a
+        ``RecordingBridge``; a serving cell replays its arrival trace."""
+        from repro.core import replay as rp
+        sess = rp.DebugSession(lambda: self._make_target(cell),
+                               label=cell.label,
+                               checkpoint_interval=checkpoint_interval)
+        if cell.serving is not None:
+            return sess, rp.record_open_loop(sess, cell.serving)
+        return sess, sess.record(lambda r: self.firmware(
+            rp.RecordingBridge(r), cell.op, cell.backend, **cell.config))
+
     def _bisect_cells(self, cell_a: SweepCell, cell_b: SweepCell,
                       checkpoint_interval: int = 8):
-        """Re-record two divergent single-device cells as deterministic
-        timelines and bisect them to the first divergent transaction
-        (core/replay.py).  The firmware runs unmodified behind a
-        ``RecordingBridge`` facade, and each recording rebuilds the cell's
-        exact fault-plan fork and congestion link, so the recorded runs
-        reproduce the sweep's bit-for-bit."""
+        """Re-record two divergent single-device or serving cells and
+        bisect them to the first divergent transaction (core/replay.py)."""
         from repro.core import replay as rp
-        if cell_a.serving is not None and cell_b.serving is not None:
-            # open-loop serving cells replay through the shared decision
-            # loop; the recording's factory rebuilds the exact
-            # backend-free fault fork the sweep ran with
-            def record_serving(cell: SweepCell):
-                def factory():
-                    plan = (cell.fault_plan.fork(cell.timing_label)
-                            if cell.fault_plan is not None else None)
-                    return self._serving_factory(cell.backend,
-                                                 cell.devices, plan)
-                sess = rp.DebugSession(
-                    factory, label=cell.label,
-                    checkpoint_interval=checkpoint_interval)
-                return sess, rp.record_open_loop(sess, cell.serving)
-
-            sa, ra = record_serving(cell_a)
-            sb, rb = record_serving(cell_b)
-            return rp.bisect_divergence(sa, ra, sb, rb)
-        if cell_a.devices != 1 or cell_b.devices != 1 \
-                or self.fabric_firmware is not None:
-            raise ValueError("divergence bisection covers single-device "
-                             "cells (fabric timelines differ per scale)")
-
-        def record(cell: SweepCell):
-            def factory():
-                plan = (cell.fault_plan.fork(cell.label)
-                        if cell.fault_plan is not None else None)
-                fb = FireBridge(congestion=cell.congestion, fault_plan=plan)
-                fb.register_op(cell.op, **self._ops[cell.op])
-                return fb
-            sess = rp.DebugSession(factory, label=cell.label,
-                                   checkpoint_interval=checkpoint_interval)
-            rec = sess.record(lambda r: self.firmware(
-                rp.RecordingBridge(r), cell.op, cell.backend,
-                **cell.config))
-            return sess, rec
-
-        sa, ra = record(cell_a)
-        sb, rb = record(cell_b)
-        return rp.bisect_divergence(sa, ra, sb, rb)
+        for c in (cell_a, cell_b):
+            if c.serving is None and (c.devices != 1
+                                      or self.fabric_firmware is not None):
+                raise ValueError("divergence bisection covers single-device "
+                                 "cells (fabric timelines differ per scale)")
+        return rp.bisect_divergence(
+            *self._record_cell(cell_a, checkpoint_interval),
+            *self._record_cell(cell_b, checkpoint_interval))
 
 
 def run_sequential(session: CoVerifySession, tol: float = 1e-3

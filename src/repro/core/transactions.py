@@ -463,10 +463,6 @@ class TransactionLog:
     def engines(self) -> List[str]:
         return sorted({t.engine for t in self.txs})
 
-    def total_stalls(self, engine: Optional[str] = None) -> float:
-        return sum(t.stall for t in self.txs
-                   if engine is None or t.engine == engine)
-
     # ------------------------------------------------------- Fig 8 analogue
     def bandwidth_timeline(self, n_buckets: int = 50,
                            by_engine: bool = True

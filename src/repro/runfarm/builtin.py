@@ -175,7 +175,6 @@ def _run_serving_campaign(unit: WorkUnit) -> UnitResult:
     the campaign digest.  Admission invariants (exact token budgets, pool
     fully drained) are checked worker-side where the engine is live."""
     from repro.core.coverage import CoverageModel
-    from repro.core.replay import target_logs
     from repro.serving import SLOReport, build_trace, run_open_loop
 
     p = unit.params
@@ -197,9 +196,7 @@ def _run_serving_campaign(unit: WorkUnit) -> UnitResult:
                                  label=f"{unit.uid}:{trace.label}")
     except Exception as e:
         failures.append(f"{type(e).__name__}: {e}")
-    violations = (list(target.violations)
-                  if hasattr(target, "violations")
-                  else list(target.mem.log.violations))
+    violations = target.violations
     engines = getattr(target, "engines", None) or [target]
     # admission invariants: every admitted request retired with its exact
     # decode budget, and every reserved page came back to the pool
@@ -223,10 +220,7 @@ def _run_serving_campaign(unit: WorkUnit) -> UnitResult:
             failures.append(f"engine {i} leaked KV pages: "
                             f"{kp.n_free}/{kp.n_pages} free after drain")
     cov = CoverageModel()
-    for log in target_logs(target):
-        for tx in log.txs:
-            cov.hit_burst(tx.nbytes)
-            cov.hit_congestion(tx.stall)
+    cov.hit_logs(target.logs())
     cov.hit("arrivals", trace.kind)
     pools = [e.kv_pool for e in engines if e.kv_pool is not None]
     deferrals = sum(kp.deferrals for kp in pools)

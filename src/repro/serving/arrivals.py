@@ -201,8 +201,7 @@ def drive_open_loop(do: Callable[..., Any], target: Any,
     scheduler (work pending/active) or fast-forward the clock to the next
     arrival (idle).  Returns the number of scheduler ticks driven.
     """
-    pending = (target._n_pending if hasattr(target, "engines")
-               else (lambda: len(target.pending)))
+    pending = target._n_pending
     arrivals = sorted(trace.arrivals, key=lambda a: (a.time, a.rid))
     i, ticks = 0, 0
     while i < len(arrivals) or pending() or target._n_active():

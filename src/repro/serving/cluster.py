@@ -10,7 +10,7 @@ across the device-local engines.  Every prompt upload crosses the shared
 host channel before it reaches the target device, and every retired
 request's token row crosses it back, so cluster serving traffic contends
 on the fabric the way the paper's DMA VIPs contend on the AXI
-interconnect (Fig. 8 statistics from ``fabric_stats()``).
+interconnect (Fig. 8 statistics from ``congestion_stats()``).
 
 Compiled executables are shared: the first engine jits prefill/decode
 once and its ``jit_fns`` seed the other devices.
@@ -31,19 +31,25 @@ from repro.core.counters import (CounterBank, CounterSpec,
 from repro.core.fabric import FABRIC_LINK
 from repro.core.registers import RO, RegisterFile
 from repro.core.switch import SwitchFabric
+from repro.core.target import Channel, ProfileView, log_digest
 from repro.core.topology import build_topology
 from repro.core.transactions import BurstBatch, TransactionLog
 # the front-end mirrors the single engine's CSR map exactly (firmware
 # drives either interchangeably); only NDEV is cluster-specific
 from repro.serving.engine import (ACTIVE, COMPLETED, CTRL, DOORBELL, STATUS,
                                   SUBMIT_ID, SUBMIT_LEN, SUBMIT_MAXNEW,
-                                  Request, ServingEngine)
+                                  Request, ServingEngine, cover_admission,
+                                  request_spans, serving_summary,
+                                  token_outputs)
 
 NDEV = 0x20
 
 
 class ClusterServingEngine:
-    """One CSR front-end, N device-local engines, one contended fabric."""
+    """One CSR front-end, N device-local engines, one contended fabric;
+    a co-verification target (core/target.py)."""
+
+    kind = "serving"
 
     def __init__(self, cfg, params, *, n_devices: int = 2,
                  max_slots: int = 2, max_len: int = 256,
@@ -385,35 +391,63 @@ class ClusterServingEngine:
             out += [f"[e{i}] {v}" for v in eng.csr.log.violations]
         return out
 
-    def fabric_stats(self) -> CongestionResult:
-        """Fig. 8 stall statistics of the shared host↔fabric channel
-        (prompt uploads + token writebacks, all engines contending)."""
-        return self.host_link.result()
-
     def profiler(self, label: str = "cluster"):
-        """Data-movement profile of the cluster (core/profiler.py): the
-        shared host channel (where ``h->e*`` prompt uploads contend with
-        ``e*->h`` token writebacks — ``serving_rows`` splits them) plus
-        every device-local engine's DDR/CSR channels."""
         from repro.core.profiler import DataMovementProfiler
         return DataMovementProfiler(self, label=label)
 
-    def congestion_stats(self) -> CongestionResult:
-        return self.fabric_stats()
+    # ------------------------------------------- target contract members
+    def logs(self) -> List[TransactionLog]:
+        return [self.log] + [e.mem.log for e in self.engines]
 
     def counter_banks(self) -> List[CounterBank]:
-        """All cluster counter banks: front (host channel + switch ports)
-        followed by every device-local engine's banks, engine order."""
+        """Front (host channel + switch ports), then every device-local
+        engine's banks, engine order."""
         out = list(self._counter_banks)
         for eng in self.engines:
             out.extend(eng.counter_banks())
         return out
 
+    @property
+    def modeled_time(self) -> float:
+        return float(self.clock)
+
+    def fault_events(self) -> List:
+        """The events of the plan the cluster was given (its engines and
+        links draw from forks of it)."""
+        plan = self._fault_plan
+        return list(plan.events) if plan is not None else []
+
+    def outputs(self) -> Dict[str, np.ndarray]:
+        return token_outputs(self.requests)
+
+    def congestion_stats(self) -> CongestionResult:
+        """Fig. 8 stall statistics of the shared host↔fabric channel
+        (prompt uploads + token writebacks, all engines contending)."""
+        return self.host_link.result()
+
+    def state_summary(self) -> dict:
+        return serving_summary(self, self.time, self.engines,
+                               self.violations)
+
+    def profile_view(self) -> ProfileView:
+        """The shared host channel (where ``h->e*`` prompt uploads contend
+        with ``e*->h`` token writebacks), the front CSR file, every switch
+        port, then each engine's channels; every engine's requests."""
+        chans = [Channel("host", "link", self.host_link),
+                 Channel("csr", "csr", self.csr)]
+        if self.switch is not None:
+            chans += [Channel(f"sw/{label}", "link", link)
+                      for label, link in self.switch.labeled_links()]
+        for i, eng in enumerate(self.engines):
+            chans += eng.profile_view(f"e{i}/").channels
+        return ProfileView(chans,
+                           requests=request_spans(enumerate(self.engines)))
+
+    def cover(self, cov) -> None:
+        """Burst sizes and congestion outcomes of every log, and the
+        KV-pool admission bins of every engine."""
+        cov.hit_logs(self.logs())
+        cover_admission(cov, self.engines, self.violations)
+
     def digest(self) -> str:
-        """Reproducibility witness over the front log and device logs."""
-        import hashlib
-        h = hashlib.sha256()
-        h.update(self.log.digest().encode())
-        for eng in self.engines:
-            h.update(eng.mem.log.digest().encode())
-        return h.hexdigest()
+        return log_digest(self)

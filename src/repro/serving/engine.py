@@ -26,6 +26,7 @@ from repro.core.bridge import MemoryBridge
 from repro.core.congestion import CongestionConfig, CongestionResult
 from repro.core.counters import CounterBank, CounterSpec
 from repro.core.registers import RO, RegisterFile
+from repro.core.target import ProfileView
 from repro.models.transformer import (RunFlags, ShardCtx, cache_insert,
                                       init_cache, make_decode_fn,
                                       make_prefill_fn)
@@ -58,7 +59,67 @@ def _copy_request(r: "Request") -> "Request":
                    r.t_first, r.t_done)
 
 
+def token_outputs(requests: Dict[int, Request]) -> Dict[str, np.ndarray]:
+    """The serving equivalence payload: every completed request's token
+    stream, compared exactly across backends and device counts."""
+    return {f"tokens[{rid}]": np.asarray(req.out_tokens, np.int64)
+            for rid, req in sorted(requests.items()) if req.done}
+
+
+def request_spans(placed) -> List[dict]:
+    """Completed continuous-batching request lifecycles of the
+    ``(device, engine)`` pairs ``placed``, as (queue, prefill, decode)
+    stamps in modeled cycles, for the profiler.  Storm-mode requests
+    carry no admission stamps (t_admit == -1) and are skipped."""
+    spans = []
+    for dev, eng in placed:
+        for rid, req in eng.requests.items():
+            if req.t_admit < 0 or req.t_done < 0:
+                continue                # storm-mode or still in flight
+            spans.append({"rid": int(rid), "device": int(dev),
+                          "t_submit": float(req.t_submit),
+                          "t_admit": float(req.t_admit),
+                          "t_first": float(req.t_first),
+                          "t_done": float(req.t_done),
+                          "tokens": len(req.out_tokens)})
+    return sorted(spans, key=lambda s: (s["t_submit"], s["rid"]))
+
+
+def serving_summary(front, time: float, engines,
+                    violations: List[str]) -> dict:
+    """``state_summary`` of a serving target: ``front`` owns the staging
+    buffers, the completion count and the merged request table."""
+    out = {"time": round(time, 6), "buffers": front.mem.buffer_digests(),
+           "completed": front.completed,
+           "tokens": {rid: list(r.out_tokens)
+                      for rid, r in sorted(front.requests.items())},
+           "violations": len(violations)}
+    pools = {f"e{i}": e.kv_pool.n_free for i, e in enumerate(engines)
+             if e.kv_pool is not None}
+    if pools:
+        out["kv_free_pages"] = pools
+    return out
+
+
+def cover_admission(cov, engines, violations: List[str]) -> None:
+    """KV-pool admission bins of a finished open-loop run: deferrals,
+    a saturated pool, an infeasible request rejected at the doorbell."""
+    pools = [e.kv_pool for e in engines if e.kv_pool is not None]
+    deferrals = sum(p.deferrals for p in pools)
+    if deferrals:
+        cov.hit("arrivals", "deferred", deferrals)
+    if any(p.peak_in_use == p.n_pages for p in pools):
+        cov.hit("arrivals", "pool_full")
+    if any("exceeds KV page pool" in v for v in violations):
+        cov.hit("arrivals", "infeasible_reject")
+
+
 class ServingEngine:
+    """One serving engine behind its CSR control plane; a co-verification
+    target (core/target.py)."""
+
+    kind = "serving"
+
     def __init__(self, cfg: ModelConfig, params, *, max_slots: int = 4,
                  max_len: int = 256,
                  flags: RunFlags = RunFlags(microbatches=1),
@@ -407,21 +468,54 @@ class ServingEngine:
     def _n_active(self) -> int:
         return sum(s is not None for s in self.slots)
 
+    def _n_pending(self) -> int:
+        return len(self.pending)
+
+    # ------------------------------------------- target contract members
+    def logs(self):
+        return [self.mem.log]
+
+    def counter_banks(self):
+        """The serving-lifecycle bank, then the DMA bridge's bank."""
+        return [self.counters, self.mem.counters]
+
+    @property
+    def modeled_time(self) -> float:
+        return float(self.clock)
+
+    @property
+    def violations(self) -> List[str]:
+        return list(self.mem.log.violations)
+
+    def fault_events(self) -> List:
+        return self.mem.fault_events()
+
+    def outputs(self) -> Dict[str, np.ndarray]:
+        return token_outputs(self.requests)
+
     def congestion_stats(self) -> Optional[CongestionResult]:
         """Fig. 8 stall statistics of the serving DMA traffic (None when
         the engine runs congestion-free)."""
         return self.mem.congestion_stats()
 
-    def counter_banks(self):
-        """All counter banks owned by this engine (core/counters.py):
-        the serving-lifecycle bank plus the DMA bridge's link bank."""
-        return [self.counters, self.mem.counters]
+    def state_summary(self) -> dict:
+        return serving_summary(self, self.mem.time, [self], self.violations)
+
+    def profile_view(self, prefix: str = "") -> ProfileView:
+        """The DMA channel (prompt uploads and token writebacks split by
+        the ``serve_dma`` read/write kind) and the CSR protocol clock,
+        names under ``prefix`` (a cluster's ``e{i}/``), and the request
+        lifecycles."""
+        return ProfileView(self.mem.channels(prefix, self.csr),
+                           requests=request_spans([(0, self)]))
+
+    def cover(self, cov) -> None:
+        """Burst sizes and congestion outcomes of the log, and the KV-pool
+        admission bins."""
+        cov.hit_logs(self.logs())
+        cover_admission(cov, [self], self.violations)
 
     def profiler(self, label: str = "serving"):
-        """Data-movement profile of the serving DMA traffic
-        (core/profiler.py): prompt-upload vs token-writeback attribution
-        rides on the ``serve_dma`` read/write split
-        (``DataMovementProfiler.serving_rows``)."""
         from repro.core.profiler import DataMovementProfiler
         return DataMovementProfiler(self, label=label)
 

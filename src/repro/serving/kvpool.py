@@ -107,9 +107,6 @@ class KVPool:
     def in_use(self) -> int:
         return self.n_pages - len(self.free)
 
-    def held_by(self, rid: int) -> List[int]:
-        return list(self.pages.get(rid, ()))
-
     # --------------------------------------------- checkpoint/restore hooks
     def get_state(self) -> dict:
         return {"free": list(self.free),

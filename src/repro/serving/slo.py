@@ -105,10 +105,8 @@ class SLOReport:
         engines = getattr(target, "engines", None) or [target]
         deferrals = sum(e.kv_pool.deferrals for e in engines
                         if e.kv_pool is not None)
-        n_violations = len(target.violations) if hasattr(
-            target, "violations") else len(target.mem.log.violations)
-        return cls(stats, float(target.clock), deferrals, n_violations,
-                   label=label)
+        return cls(stats, target.modeled_time, deferrals,
+                   len(target.violations), label=label)
 
     # ------------------------------------------------------------- metrics
     @property

@@ -388,7 +388,7 @@ def test_cluster_serving_matches_single_engine():
         assert single.requests[rid].out_tokens == \
             clu.requests[rid].out_tokens
     # prompt upload + token writeback both crossed the shared channel
-    st = clu.fabric_stats()
+    st = clu.congestion_stats()
     assert any(e.startswith("h->e") for e in st.per_engine_stall)
     assert any(e.startswith("e") and "->h" in e
                for e in st.per_engine_stall)

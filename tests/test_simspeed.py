@@ -416,7 +416,7 @@ def test_link_result_timeline_is_a_snapshot():
 
 
 def test_fabric_stats_match_the_eager_results():
-    """``FabricCluster.device_congestion()`` and the switch's port stats
+    """``FabricCluster.congestion_stats()`` and the switch's port stats
     on one small routed, congested fabric equal what eager link results
     give, and neither builds a Transaction to get them."""
     from repro.core import FABRIC_LINK, FabricCluster, ring
@@ -427,7 +427,7 @@ def test_fabric_stats_match_the_eager_results():
     fab.register_op("mm", **matmul_backends(tile=16, jit=False))
     matmul_fabric_firmware(fab, "mm", "oracle", size=32, tile=16)
     built = materialized_tally()
-    merged = fab.device_congestion()
+    merged = fab.congestion_stats()
     ports = fab.switch.port_stats()
     assert materialized_tally() == built
     per = [(i, _eager_result(d.mem.link)) for i, d in enumerate(fab.devices)]
