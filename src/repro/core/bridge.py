@@ -38,7 +38,7 @@ from repro.core.counters import (CounterBank, CounterSpec,
                                  register_link_counters)
 from repro.core.registers import RegisterFile
 from repro.core.spans import span
-from repro.core.target import Channel, ProfileView
+from repro.core.target import Channel, ProfileView, lend
 from repro.core.transactions import (BurstBatch, OpMark, Transaction,
                                      TransactionLog, record_mark)
 
@@ -404,8 +404,8 @@ class FireBridge:
         return self.mem.fault_events()
 
     def outputs(self) -> Dict[str, np.ndarray]:
-        """Final DDR state, every buffer copied."""
-        return {n: b.array.copy() for n, b in self.mem.buffers.items()}
+        """Final DDR state, every buffer lent read-only (``lend``)."""
+        return {n: lend(b.array) for n, b in self.mem.buffers.items()}
 
     def congestion_stats(self) -> Optional[CongestionResult]:
         """Per-engine stall/busy/utilization accumulated online (Fig. 8)."""

@@ -58,7 +58,7 @@ from repro.core.counters import (CounterBank, CounterSpec,
                                  register_link_counters,
                                  register_switch_port_counters)
 from repro.core.switch import SwitchFabric
-from repro.core.target import Channel, ProfileView, log_digest
+from repro.core.target import Channel, ProfileView, lend, log_digest
 from repro.core.topology import Topology, build_topology
 from repro.core.transactions import (BurstBatch, OpMark, Transaction,
                                      TransactionLog, record_mark)
@@ -781,8 +781,9 @@ class FabricCluster:
         return evs
 
     def outputs(self) -> Dict[str, np.ndarray]:
-        """Host-visible final state (the cross-scale equivalence surface)."""
-        return {n: b.array.copy() for n, b in self.host.buffers.items()}
+        """Host-visible final state (the cross-scale equivalence surface),
+        every host buffer lent read-only (``lend``)."""
+        return {n: lend(b.array) for n, b in self.host.buffers.items()}
 
     def digest(self) -> str:
         return log_digest(self)

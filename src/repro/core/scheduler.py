@@ -90,6 +90,12 @@ def _nbytes(outputs: Dict[str, np.ndarray]) -> int:
     return sum(a.nbytes for a in outputs.values())
 
 
+def _copied(outputs: Dict[str, np.ndarray]) -> int:
+    """Bytes of the collected outputs that own their memory: built for
+    the collect, not lent by the target."""
+    return sum(a.nbytes for a in outputs.values() if a.flags.owndata)
+
+
 @contextlib.contextmanager
 def _collect_span():
     """``fb.cell.collect`` around one finished cell's summaries, with the
@@ -548,7 +554,9 @@ class CoVerifySession:
         number of its sweep: a pool thread's spans join the caller's.
         The target is built and the program run in ``fb.firmware``; the
         ``fb.cell.collect`` span then reads the result through the target
-        contract alone."""
+        contract alone.  The collect is the target's last reader, so it
+        keeps the outputs the target lends: the span's ``copied`` counts
+        the output bytes built for the collect, ``bytes`` all of them."""
         with span("fb.cell", sweep=sweep, cell=cell.label):
             cov = CoverageModel() if self.coverage is not None else None
             err: Optional[str] = None
@@ -563,7 +571,7 @@ class CoVerifySession:
                 if cov is not None:
                     target.cover(cov)
                 outputs = target.outputs()
-                col.set(bytes=_nbytes(outputs))
+                col.set(bytes=_nbytes(outputs), copied=_copied(outputs))
                 return CellResult(
                     cell=cell,
                     outputs=outputs,

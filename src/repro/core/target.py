@@ -14,6 +14,8 @@ import hashlib
 from typing import (Any, Dict, FrozenSet, List, NamedTuple, Optional,
                     Protocol, Tuple, runtime_checkable)
 
+import numpy as np
+
 
 class Channel(NamedTuple):
     """One labelled channel for the profiler.  ``kind`` "link": ``source``
@@ -62,7 +64,9 @@ class CoVerifyTarget(Protocol):
         """The ``fuzz.FaultEvent``s injected so far."""
 
     def outputs(self) -> Dict[str, Any]:
-        """The equivalence surface the sweep diffs, copied."""
+        """The equivalence surface the sweep diffs: the final state, lent
+        read-only; valid until the target runs again, and the session's
+        collect is its last reader."""
 
     def congestion_stats(self) -> Optional[Any]:
         """``CongestionResult`` of the memory traffic, or None."""
@@ -84,6 +88,14 @@ class CoVerifyTarget(Protocol):
 
     def profiler(self, label: Optional[str] = None) -> Any:
         """The target's ``DataMovementProfiler``."""
+
+
+def lend(array: np.ndarray) -> np.ndarray:
+    """A read-only view of ``array``: how ``outputs()`` lends a buffer
+    without copying it."""
+    view = array.view()
+    view.flags.writeable = False
+    return view
 
 
 def log_digest(target: CoVerifyTarget) -> str:

@@ -157,6 +157,26 @@ def test_sequential_and_batched_agree():
             np.testing.assert_array_equal(a.outputs[name], b.outputs[name])
 
 
+def test_a_report_keeps_its_outputs_across_sweeps():
+    """Each cell lends its finished target's DDR to the report: a second
+    sweep of the same session builds new targets, so it leaves the first
+    report's outputs as they were and shares no memory with them."""
+    sess = _session()
+    sess.add_sweep("mm", ("oracle", "interpret"), [{"size": 32}])
+    first = sess.run(max_workers=2)
+    held = [{n: a.copy() for n, a in r.outputs.items()}
+            for r in first.cells]
+    second = sess.run(max_workers=2)
+    assert first.passed and second.passed
+    for r, kept in zip(first.cells, held):
+        assert set(r.outputs) == set(kept)
+        for name, arr in r.outputs.items():
+            np.testing.assert_array_equal(arr, kept[name])
+            assert not any(np.shares_memory(arr, b)
+                           for s in second.cells
+                           for b in s.outputs.values())
+
+
 def test_report_is_independent_of_thread_completion_order():
     """Satellite regression: on a seeded 20-cell sweep (faults + online
     congestion + a coverage sink + one planted divergence), report rows,

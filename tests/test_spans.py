@@ -124,3 +124,19 @@ def test_collect_builds_no_transaction(tmp_path):
     assert all(0 < c["runs"] < c["samples"] for c in collects)
     assert [c["materialized"] for c in collects] == [0, 0]
     assert all(c["bytes"] > 0 for c in collects)
+
+
+def test_collect_copies_no_bridge_output(tmp_path):
+    """A bridge cell's collect keeps the DDR its finished target lends:
+    ``copied`` reads 0, and ``bytes`` is still every buffer's size (a, b
+    and c of a 32 x 32 float32 matmul)."""
+    sess = CoVerifySession(matmul_firmware)
+    sess.register_op("mm", **matmul_backends(tile=16, jit=False))
+    sess.add_sweep("mm", ("oracle", "interpret"), [{"size": 32}])
+    jax.profiler.start_trace(str(tmp_path))
+    rep = sess.run(max_workers=2)
+    jax.profiler.stop_trace()
+    assert rep.passed
+    collects = _host_events(tmp_path, "fb.cell.collect")
+    assert [(c["bytes"], c["copied"]) for c in collects] == \
+        [(3 * 32 * 32 * 4, 0)] * 2

@@ -232,6 +232,24 @@ def test_every_kind_meets_the_contract(kind):
     assert _congestion(target.congestion_stats()) == pin["congestion"]
 
 
+@pytest.mark.parametrize("kind", ["bridge", "fabric"])
+def test_outputs_lend_the_final_state_read_only(kind):
+    """``outputs()`` copies nothing: every buffer is a read-only view of
+    the target's own DDR (a fabric's host buffers), which a write cannot
+    change through it, and it holds the pinned bytes."""
+    target, _ = BUILDERS[kind]()
+    ddr = (target.mem if kind == "bridge" else target.host).buffers
+    outputs = target.outputs()
+    assert set(outputs) == set(ddr)
+    for name, arr in outputs.items():
+        assert np.shares_memory(arr, ddr[name].array)
+        assert not arr.flags.writeable
+        with pytest.raises(ValueError):
+            arr[(0,) * arr.ndim] = 1
+    assert _digest(outputs) == PINS[kind]["outputs"]
+    assert _digest(target.outputs()) == PINS[kind]["outputs"]
+
+
 def _bridge_session(plan):
     sess = CoVerifySession(_firmware, congestion=CONG, fault_plan=plan)
     sess.register_op("mm", oracle=_matmul)
